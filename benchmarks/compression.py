@@ -21,7 +21,9 @@ def main(out=None):
         # re-exec in a clean process with the device-count flag set
         import subprocess
         import sys
-        env = {**os.environ,
+        # the child compiles for fake host devices: it never needs (and
+        # must not contend with its parent for) an accelerator
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "XLA_FLAGS": "--xla_force_host_platform_device_count=512"}
         r = subprocess.run([sys.executable, "-m", "benchmarks.compression"],
                            capture_output=True, text=True, env=env,
@@ -31,8 +33,8 @@ def main(out=None):
                 out.append(line)
                 print(line)
         if r.returncode != 0:
-            out.append(f"compress.ERROR,0,{r.stderr[-300:]}")
-            print(out[-1])
+            raise RuntimeError(f"compression child failed: "
+                               f"{r.stderr[-300:]}")
         return
 
     import jax

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import sys
 import time
 from typing import List
 
@@ -76,11 +77,14 @@ def main() -> None:
     ap.add_argument("--out", default="results/bench.csv",
                     help="CSV output path")
     args = ap.parse_args()
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
 
     if args.quick:
         os.environ["BENCH_QUICK"] = "1"
     want = args.suites or (QUICK_SUITES if args.quick else list(SUITES))
     rows: List[str] = []
+    failed: List[str] = []
     for key in want:
         if key not in SUITES:
             print(f"unknown suite {key!r}; known: {list(SUITES)}")
@@ -91,9 +95,10 @@ def main() -> None:
         mod = __import__(mod_name, fromlist=["main"])
         try:
             mod.main(rows)
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # run the rest, then exit non-zero
             rows.append(f"{key}.ERROR,0,{type(e).__name__}:{e}")
             print(rows[-1])
+            failed.append(key)
         print(f"--- {key} done in {time.time() - t0:.1f}s")
     out_dir = os.path.dirname(args.out)
     if out_dir:
@@ -102,6 +107,8 @@ def main() -> None:
         f.write("name,us_per_call,derived\n")
         f.write("\n".join(rows) + "\n")
     print(f"\n{len(rows)} rows -> {args.out}")
+    if failed:
+        sys.exit(f"suites that raised: {failed}")
 
 
 if __name__ == "__main__":

@@ -252,16 +252,19 @@ _FP_FIELDS = ("base", "size", "magic_m", "magic_s")
 def _fence_params_flatten(fp: "FenceParams"):
     """Pytree flattening with a *per-instance* static/dynamic split.
 
-    Array-valued fields (traced bounds, magic-row columns) are children so
-    FenceParams can ride through ``jax.jit`` as an operand (the jitted
-    trusted-step path passes GuardSpecs this way); host-int fields stay
-    aux data so a static-bounds guard keeps its concrete values — the
+    Array-valued fields (traced bounds, magic-row columns, or their
+    ``jax.ShapeDtypeStruct`` stand-ins when a step is only lowered) are
+    children so FenceParams can ride through ``jax.jit`` as an operand
+    (the jitted trusted-step path passes GuardSpecs this way); host-int
+    fields stay aux data so a static-bounds guard keeps its concrete
+    values — the
     MODULO static path needs a concrete size for its shift amount, and
     baking static bounds into the compiled step matches the eager path
     bit-for-bit.
     """
     vals = tuple(getattr(fp, n) for n in _FP_FIELDS)
-    is_dyn = tuple(isinstance(v, (jax.Array, np.ndarray)) for v in vals)
+    is_dyn = tuple(isinstance(v, (jax.Array, np.ndarray,
+                                  jax.ShapeDtypeStruct)) for v in vals)
     children = tuple(v for v, d in zip(vals, is_dyn) if d)
     static = tuple(None if d else v for v, d in zip(vals, is_dyn))
     return children, (is_dyn, static)

@@ -90,7 +90,6 @@ from repro.core.scheduler import (
     LaunchRequest,
     LRUCache,
     _arg_signature,
-    donation_supported,
 )
 from repro.core.telemetry import DRAIN_TRACK, Telemetry
 from repro.core.tenantclass import ClassSpec, TenantClassPolicy, \
@@ -147,8 +146,7 @@ class _KernelEntry:
     #: unfused fallback.
     trusted: bool = False
     #: fn-arg positions (arena = 0) whose buffers the jitted trusted step
-    #: may donate — consumed-once operands like the engine's KV cache;
-    #: ignored on backends without donation (CPU)
+    #: may donate — consumed-once operands like the engine's KV cache
     donate_argnums: Tuple[int, ...] = ()
     #: name of a manager :class:`PoolArena` threaded through the step as
     #: its second argument — ``fn(arena, pool, *args) ->
@@ -842,8 +840,7 @@ class GuardianManager:
         ``donate_argnums`` are fn-arg positions (arena = 0) whose buffers
         the compiled step may consume in place — operands used exactly
         once per step, like the engine's KV cache; shared operands (the
-        per-run guard) must not be listed.  Ignored where the backend
-        does not implement donation (CPU).
+        per-run guard) must not be listed.
 
         ``pool_arena`` names a manager pool (see :meth:`register_pool`)
         threaded through the step: the contract becomes
@@ -909,7 +906,7 @@ class GuardianManager:
         signature already computed for the request (the scheduler hot
         path caches it) instead of re-flattening the operand pytrees.
         Declared ``donate_argnums`` buffers (plus the arena) alias in
-        place on backends with donation; the cache is LRU-bounded like
+        place; the cache is LRU-bounded like
         every fenced jit cache."""
         key = ("trusted",
                _arg_signature(call_args) if arg_sig is None else arg_sig)
@@ -917,9 +914,7 @@ class GuardianManager:
         if fn is None:
             if entry.verify:
                 self._verify_trusted(entry, call_args)
-            if not donation_supported():
-                donate = ()
-            elif entry.pool_arena is not None:
+            if entry.pool_arena is not None:
                 # arena + threaded pool; declared argnums shift past pool
                 donate = (0, 1, *(i + 1 for i in entry.donate_argnums
                                   if i > 0))
@@ -1103,9 +1098,9 @@ class GuardianManager:
 
         if entry.trusted:
             # framework step: internally fenced, no augmentation — jitted
-            # (keyed by operand signature, pool/arena donated where the
-            # backend supports it) unless jit_trusted is off, in which
-            # case the eager fallback runs — see register_trusted_kernel
+            # (keyed by operand signature, pool/arena donated) unless
+            # jit_trusted is off, in which case the eager fallback runs —
+            # see register_trusted_kernel
             t1 = time.perf_counter_ns()
             if self.jit_trusted:
                 fn = self._trusted_exec(entry, req.call_args,

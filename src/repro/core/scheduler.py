@@ -110,12 +110,6 @@ from repro.core.telemetry import Histogram, QUEUE_AGE_BOUNDS, \
     SCHEDULER_TRACK
 
 
-def donation_supported() -> bool:
-    """Whether ``jax.jit`` buffer donation does anything on this backend
-    (CPU ignores donation and warns; GPU/TPU alias in place)."""
-    return jax.default_backend() not in ("cpu",)
-
-
 class LRUCache(collections.OrderedDict):
     """Capacity-bounded dict with least-recently-used eviction.
 
@@ -912,9 +906,9 @@ class BatchedLaunchScheduler:
         threading the arena — and the entry's pool arena, when declared —
         row to row, so engine r+1's step sees engine r's pool updates
         exactly as in the per-launch drain.  The donated-operand split
-        lets each row's consumed buffers alias in place on backends that
-        support donation, while shared operands (the per-step guard,
-        reused every step) are never donated."""
+        lets each row's consumed buffers alias in place, while shared
+        operands (the per-step guard, reused every step) are never
+        donated."""
         donate = tuple(i for i in getattr(entry, "donate_argnums", ())
                        if i > 0)
 
@@ -940,12 +934,7 @@ class BatchedLaunchScheduler:
                     outs.append(out)
                 return arena, pool, tuple(outs)
 
-        if not donation_supported():
-            dn = ()
-        elif entry.pool_arena is not None:
-            dn = (0, 1, 2)
-        else:
-            dn = (0, 1)
+        dn = (0, 1, 2) if entry.pool_arena is not None else (0, 1)
         return jax.jit(fused, donate_argnums=dn)
 
     # ------------------------------------------------------------------ #

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the Guardian hot paths.
 
 Each kernel: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper, interpret-mode on CPU), ref.py (pure-jnp oracle).
+wrapper, ``interpret`` required), ref.py (pure-jnp oracle).
 """
 
 from repro.kernels.ops import (

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _fence(idx, base, mask):
     return jax.lax.bitwise_or(jax.lax.bitwise_and(idx, mask), base)
@@ -35,7 +33,7 @@ def _kernel(idx_ref, base_ref, mask_ref, row_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fenced_gather(table, idx, fence_base, fence_mask, *, interpret=True):
+def fenced_gather(table, idx, fence_base, fence_mask, *, interpret: bool):
     """table (V, D); idx (N,) int32 -> (N, D) with fenced row ids."""
     V, D = table.shape
     N = idx.shape[0]
@@ -49,7 +47,7 @@ def fenced_gather(table, idx, fence_base, fence_mask, *, interpret=True):
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, D), table.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )

@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -128,7 +126,7 @@ def _kernel_mapped(page_table, seq_lens, base, mask, page_map,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fenced_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                            fence_base, fence_mask, page_map=None, *,
-                           interpret=True):
+                           interpret: bool):
     """q (B,H,D); pools (P,page,KH,D); returns (B,H,D).
 
     ``page_map`` (n_virt,) int32, optional: page_table then holds
@@ -174,7 +172,7 @@ def fenced_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
         kernel_fn,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )

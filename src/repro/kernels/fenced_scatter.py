@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _fence(idx, base, mask):
     return jax.lax.bitwise_or(jax.lax.bitwise_and(idx, mask), base)
@@ -37,7 +35,7 @@ def _kernel(ids_ref, base_ref, mask_ref, pages_ref, pool_in_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnums=(0,))
 def fenced_scatter(pool, pages, page_ids, fence_base, fence_mask, *,
-                   interpret=True):
+                   interpret: bool):
     """pool (P,page,KH,D); pages (N,page,KH,D); page_ids (N,) -> pool'."""
     P, page, KH, D = pool.shape
     N = pages.shape[0]
@@ -55,7 +53,7 @@ def fenced_scatter(pool, pages, page_ids, fence_base, fence_mask, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={4: 0},   # pool aliases the output
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )

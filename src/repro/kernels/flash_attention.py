@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -65,7 +63,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    static_argnames=("causal", "q_blk", "kv_blk",
                                     "interpret"))
 def flash_attention(q, k, v, *, causal=True, q_blk=128, kv_blk=128,
-                    interpret=True):
+                    interpret: bool):
     """q (B,S,H,D); k/v (B,S,KH,D) -> (B,S,H,D)."""
     B, S, H, D = q.shape
     KH = k.shape[2]
@@ -101,7 +99,7 @@ def flash_attention(q, k, v, *, causal=True, q_blk=128, kv_blk=128,
             pltpu.VMEM((q_blk, D), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

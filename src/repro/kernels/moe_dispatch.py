@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(base_ref, mask_ref, ids_ref, o_ref, *, num_experts, blk):
     t = pl.program_id(0)
@@ -37,7 +35,7 @@ def _kernel(base_ref, mask_ref, ids_ref, o_ref, *, num_experts, blk):
 
 @functools.partial(jax.jit, static_argnames=("num_experts", "interpret"))
 def moe_histogram(expert_ids, num_experts, fence_base, fence_mask, *,
-                  interpret=True):
+                  interpret: bool):
     """expert_ids (T, K) int32 -> counts (num_experts,)."""
     T, K = expert_ids.shape
     blk = min(T, 256)
@@ -56,7 +54,7 @@ def moe_histogram(expert_ids, num_experts, fence_base, fence_mask, *,
             out_specs=pl.BlockSpec((1, num_experts), lambda t, b, m: (0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((1, num_experts), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )

@@ -82,8 +82,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):  # jax<=0.4.x: one dict per program
-        xla_cost = xla_cost[0] if xla_cost else {}
     hlo = compiled.as_text()
     costs = analyze_hlo(hlo)
 
@@ -228,7 +226,10 @@ def main():
                     cmd += ["--remat-policy", args.remat_policy]
                 if args.tag:
                     cmd += ["--tag", args.tag]
-                r = subprocess.run(cmd)
+                # the cell compiles for fake host devices: keep the child
+                # off any accelerator this host has
+                r = subprocess.run(cmd, env={**os.environ,
+                                             "JAX_PLATFORMS": "cpu"})
                 if r.returncode != 0:
                     failures.append((arch, shape_name))
         if failures:

@@ -8,17 +8,11 @@ module never touches jax device state — required for the dry-run's
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: meshes are implicitly Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -65,6 +65,7 @@ from repro.core.partition import OutOfArenaMemory
 from repro.core.quarantine import QuarantinePolicy, TenantState
 from repro.core.scheduler import round_robin_interleave
 from repro.core.violations import NUM_KINDS, ViolationKind
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.steps import (
     build_pool_relocation_step,
     build_trusted_serve_steps,
@@ -643,7 +644,13 @@ class ServeEngine:
                     self.cfg.moe.num_experts)) if self.cfg.moe else None),
                 page=FenceParams(base=0, size=self._n_phys),
                 row_policy=row_policy,
-                page_map=jnp.asarray(self._pool.pages.page_map),
+                # a snapshot: the host rewrites the map in place at later
+                # cycle boundaries (join, leave, elastic rebase) while this
+                # step may still wait in the device queue, and the CPU
+                # backend adopts an aligned host array without copying
+                # it, so the step would read the map as it is when it
+                # runs, not as it was when it was handed over
+                page_map=jnp.asarray(self._pool.pages.page_map.copy()),
             )
         pages = self.cache.kv.pages_per_slot if hasattr(self.cache, "kv") \
             else (self.cache.pages_per_slot if hasattr(self.cache, "k")
@@ -1334,6 +1341,7 @@ def main(argv=None):
                          "as Chrome/Perfetto trace_event JSON to this "
                          "path (load in ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

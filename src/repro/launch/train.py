@@ -53,6 +53,9 @@ def main():
                          "this step (schedule still spans --steps)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
+
     from repro.checkpoint import CheckpointStore
     from repro.configs import ShapeConfig, get_config
     from repro.core.fence import FencePolicy

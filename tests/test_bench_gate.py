@@ -270,3 +270,36 @@ def test_render_history_markdown_report():
     assert "| 1.000 | 1.200 | 1.400 |" in row
     # empty history still renders a valid document
     assert "_(no rows yet)_" in render_markdown("")
+
+
+def test_run_exits_nonzero_when_a_suite_raised(tmp_path, monkeypatch):
+    """benchmarks.run keeps running the suites after one raises, writes
+    every row, and then exits non-zero naming the suite that raised."""
+    import types
+
+    from benchmarks import run
+
+    def ok(rows):
+        rows.append("ok.row,1.00,")
+
+    def boom(rows):
+        raise RuntimeError("suite failed")
+
+    for name, fn in (("_suite_boom", boom), ("_suite_ok", ok)):
+        mod = types.ModuleType(name)
+        mod.main = fn
+        monkeypatch.setitem(sys.modules, name, mod)
+    monkeypatch.setattr(run, "SUITES", {"boom": ("_suite_boom", ""),
+                                        "ok": ("_suite_ok", "")})
+    # a cache directory from the environment: the harness sets none
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    out = tmp_path / "bench.csv"
+    monkeypatch.setattr(sys, "argv", ["run", "boom", "ok", "--out",
+                                      str(out)])
+    with pytest.raises(SystemExit) as exc:
+        run.main()
+    assert exc.value.code not in (0, None)
+    assert "boom" in str(exc.value.code)
+    rows = out.read_text().splitlines()
+    assert rows[1].startswith("boom.ERROR,0,RuntimeError")
+    assert rows[2] == "ok.row,1.00,"

@@ -203,6 +203,32 @@ def test_join_leave_never_aliases_freed_page():
         assert len(out[rid]) == 1 + i % 3
 
 
+def test_step_guard_page_map_is_a_snapshot():
+    """A step's page_map operand is fixed when the step is launched.  The
+    host rewrites the PagePool map in place at later cycle boundaries
+    (join, leave, elastic rebase) while the step may still wait in the
+    device queue.  On the CPU, JAX adopts a 64-byte-aligned host array
+    without copying it, so the guard must hand over a snapshot: put the
+    map at an aligned address, rewrite it after the guard was built, and
+    the guard must still hold the map as it was."""
+    mgr = make_shared_manager(1, max_batch=2, paged=True, max_len=256)
+    eng = ServeEngine(CFG, max_batch=2, max_len=256, seed=0,
+                      manager=mgr, paged=True)
+    eng.register_tenant("t", 4)
+    pages = eng._pool.pages
+    n = pages.page_map.size
+    raw = np.zeros((n + 16,), np.int32)
+    off = (-raw.ctypes.data % 64) // raw.itemsize
+    aligned = raw[off:off + n]
+    aligned[:] = pages.page_map
+    pages.page_map = aligned
+    want = aligned.copy()
+    assert want.any()
+    guard = eng._guard_for_rows([None, None])
+    pages.release_extent("t")        # zeroes the tenant's map rows
+    np.testing.assert_array_equal(np.asarray(guard.page_map), want)
+
+
 # --------------------------------------------------------------------- #
 # Zero-copy elasticity                                                  #
 # --------------------------------------------------------------------- #

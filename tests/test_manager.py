@@ -262,13 +262,10 @@ def test_pool_slot_map_rewrite_defers_under_queued_decodes():
     assert float(mgr.arenas["kv"].buf["k"][0, part.base, 0]) == 3.0
 
 
-def test_trusted_donation_declared_but_noop_on_cpu():
-    """donate_argnums on a trusted kernel is compiled in but inert on
-    CPU (donation_supported() is False): the donated operand's buffer
-    survives the call — the documented no-op — and results match."""
-    from repro.core.scheduler import donation_supported
-
-    assert not donation_supported()               # CPU test environment
+def test_trusted_donation_consumes_operand():
+    """donate_argnums on a trusted kernel aliases in place on every
+    backend, the CPU included: the donated operand and the arena that
+    went into the step are consumed, and the result is unchanged."""
     mgr = make_manager(total_slots=64)
 
     def step(arena, consumed, x):
@@ -277,13 +274,16 @@ def test_trusted_donation_declared_but_noop_on_cpu():
     mgr.register_trusted_kernel("dstep", step, donate_argnums=(1,))
     c = mgr.register_tenant("svc", 16)
     buf = jnp.full((8,), 3.0, jnp.float32)
+    arena_in = mgr.arena.buf
     req = c.launch_kernel("dstep", args=(buf, jnp.float32(5.0)))
     mgr.synchronize()
     np.testing.assert_array_equal(np.asarray(req.result),
                                   np.full(8, 5.0, np.float32))
-    # no donation happened: the operand is still alive and readable
-    np.testing.assert_array_equal(np.asarray(buf),
-                                  np.full(8, 3.0, np.float32))
+    # donation happened: the operand and the old arena are gone, and the
+    # manager holds the step's live arena instead
+    assert buf.is_deleted()
+    assert arena_in.is_deleted()
+    assert not mgr.arena.buf.is_deleted()
     # and the compiled entry cached under the trusted key
     entry = mgr.pointer_to_symbol["dstep"]
     assert any(k[0] == "trusted" for k in entry.jit_cache)
