@@ -34,9 +34,9 @@ def main(out: List[str]):
     c.synchronize()
     # drop the first (tracing) samples
     stats = mgr.launch_stats
-    lookup = float(np.median(stats.lookup_ns[10:]))
-    augment = float(np.median(stats.augment_ns[10:]))
-    dispatch = float(np.median(stats.dispatch_ns[10:]))
+    lookup = float(np.median(list(stats.lookup_ns)[10:]))
+    augment = float(np.median(list(stats.augment_ns)[10:]))
+    dispatch = float(np.median(list(stats.dispatch_ns)[10:]))
     total = lookup + augment
     out.append(f"table5.lookup_ns,{lookup / 1e3:.3f},paper=214-900cycles")
     out.append(f"table5.augment_ns,{augment / 1e3:.3f},paper=300-600cycles")

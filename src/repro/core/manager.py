@@ -56,7 +56,8 @@ import collections
 import dataclasses
 import enum
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, \
+    Tuple
 
 import jax
 import jax.numpy as jnp
@@ -91,7 +92,12 @@ from repro.core.scheduler import (
     LRUCache,
     _arg_signature,
 )
-from repro.core.telemetry import DRAIN_TRACK, Telemetry
+from repro.core.telemetry import (
+    DRAIN_TRACK,
+    SPAN_MANAGER_DRAIN,
+    SPAN_SCHEDULER_FLUSH,
+    Telemetry,
+)
 from repro.core.tenantclass import ClassSpec, TenantClassPolicy, \
     as_class_policy
 from repro.core.violations import ViolationLog
@@ -107,13 +113,19 @@ class SharingMode(enum.Enum):
     SPATIAL = "spatial"         # concurrent streams, round-robin issue
 
 
-@dataclasses.dataclass
 class LaunchStats:
-    """Table 5 analogue: cycles -> nanoseconds on the host."""
+    """Table 5 analogue: cycles -> nanoseconds on the host.  Each series
+    is a ring of the last :attr:`KEEP` launches, and holds nothing when
+    telemetry is off: a process that launches billions of kernels keeps
+    a bounded sample, never one int per launch."""
 
-    lookup_ns: List[int] = dataclasses.field(default_factory=list)
-    augment_ns: List[int] = dataclasses.field(default_factory=list)
-    dispatch_ns: List[int] = dataclasses.field(default_factory=list)
+    KEEP = 4096
+
+    def __init__(self, enabled: bool = True):
+        keep = self.KEEP if enabled else 0
+        self.lookup_ns: Deque[int] = collections.deque(maxlen=keep)
+        self.augment_ns: Deque[int] = collections.deque(maxlen=keep)
+        self.dispatch_ns: Deque[int] = collections.deque(maxlen=keep)
 
     def summary(self) -> Dict[str, float]:
         def avg(xs):
@@ -304,7 +316,7 @@ class GuardianManager:
 
         self._queues: "collections.OrderedDict[str, collections.deque]" = (
             collections.OrderedDict())
-        self.launch_stats = LaunchStats()
+        self.launch_stats = LaunchStats(enabled=telemetry)
         self.violations: List[str] = []
         self._export_tables: Dict[int, Dict[str, Any]] = {
             # minimal cudaGetExportTable implementation (§4.1): enough
@@ -1224,6 +1236,10 @@ class GuardianManager:
         this returns.
         TIME_SHARE: drain each tenant fully then block (context switch).
         """
+        with self.telemetry.span(SPAN_MANAGER_DRAIN):
+            self._drain()
+
+    def _drain(self) -> None:
         if self.mode is SharingMode.SPATIAL:
             tel = self.telemetry
             # hoisted bindings: this loop runs once per drain cycle and
@@ -1243,7 +1259,8 @@ class GuardianManager:
                     for _ in range(min(self.weight_of(t), len(q))):
                         self._run_op(q.popleft())
                     pending = pending or bool(q)
-                self.scheduler.flush(drain=not pending)
+                with tel.span(SPAN_SCHEDULER_FLUSH):
+                    self.scheduler.flush(drain=not pending)
                 # containment check at the cycle boundary: a tenant crossing
                 # the violation threshold here has its remaining queued ops
                 # dropped while co-tenants keep draining (skipped entirely

@@ -934,6 +934,10 @@ class BatchedLaunchScheduler:
                     outs.append(out)
                 return arena, pool, tuple(outs)
 
+        # named after the member step (``jit_fused_decode_step``), so a
+        # device-trace reader that finds the solo step finds the fused one
+        fused.__name__ = fused.__qualname__ = \
+            f"fused_{getattr(entry.fn, '__name__', 'step')}"
         dn = (0, 1, 2) if entry.pool_arena is not None else (0, 1)
         return jax.jit(fused, donate_argnums=dn)
 

@@ -22,6 +22,11 @@ module unifies them behind two host-side primitives:
   per tenant, one per scheduler, one per completed serve request) for
   ``ui.perfetto.dev``.  Ring overflow is counted (:attr:`EventTrace.
   dropped`) and surfaced in ``metrics_report()`` / ``repro.top``.
+* :meth:`Telemetry.span` — host spans of the serve loop and the manager
+  drain (``guardian.serve.cycle`` and its children, :data:`HOST_SPANS`),
+  written as ``jax.profiler.TraceAnnotation``\\ s: a profiler trace holds
+  them in the same xplane and on the same clock as the device's XLA ops,
+  so a device idle gap can be laid against the host work around it.
 * :class:`RequestSpan` / :class:`SpanLedger` — request-level tracing for
   the serving plane.  Every serve request owns a span whose lifetime is
   partitioned into phases (``queue``/``hold``/``prefill``/``decode``/
@@ -59,11 +64,14 @@ dashboard (:mod:`repro.launch.dashboard`).
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, Iterable, List, Optional, \
     Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Histogram",
@@ -80,6 +88,8 @@ __all__ = [
     "E2E_CYCLE_BOUNDS",
     "SPAN_PHASES",
     "SLACK_PHASES",
+    "HOST_SPAN_PREFIX",
+    "HOST_SPANS",
 ]
 
 #: global (non-tenant) series key inside the registry maps — a plain
@@ -97,6 +107,49 @@ SLOTS_BOUNDS: Tuple[float, ...] = tuple(float(4 ** i) for i in range(16))
 #: end-to-end request latencies in drain cycles: pow2 up to 4096
 E2E_CYCLE_BOUNDS: Tuple[float, ...] = \
     (0.0,) + tuple(float(1 << i) for i in range(13))
+
+
+#: prefix of every host span :meth:`Telemetry.span` writes into a profiler
+#: trace (the benchmark's own spans are ``bench.*``)
+HOST_SPAN_PREFIX = "guardian."
+#: host spans, by name without the prefix.  ``serve_continuous`` writes
+#: one ``serve.call`` per call, one ``serve.cycle`` per busy drain cycle
+#: and ``serve.finalize`` at its end; ``serve.submit`` wraps
+#: ``ServeEngine.submit``; ``manager.drain`` wraps every
+#: ``GuardianManager.run_queued``.
+SPAN_SERVE_CALL = "serve.call"
+SPAN_SERVE_CYCLE = "serve.cycle"
+SPAN_SERVE_LEAVE = "serve.leave"
+SPAN_SERVE_JOIN = "serve.join"
+SPAN_SERVE_DISPATCH = "serve.dispatch"
+SPAN_SERVE_GUARD = "serve.guard"
+SPAN_SERVE_META = "serve.meta"
+SPAN_SERVE_LAUNCH = "serve.launch"
+SPAN_MANAGER_DRAIN = "manager.drain"
+SPAN_SCHEDULER_FLUSH = "scheduler.flush"
+SPAN_SERVE_FINISH = "serve.finish"
+SPAN_SERVE_GAUGES = "serve.gauges"
+SPAN_SERVE_FINALIZE = "serve.finalize"
+SPAN_SERVE_SUBMIT = "serve.submit"
+#: every host span beside the span it nests in on the serve path (None:
+#: outermost)
+HOST_SPANS: Tuple[Tuple[str, Optional[str]], ...] = (
+    (SPAN_SERVE_CALL, None),
+    (SPAN_SERVE_CYCLE, SPAN_SERVE_CALL),
+    (SPAN_SERVE_LEAVE, SPAN_SERVE_CYCLE),
+    (SPAN_SERVE_JOIN, SPAN_SERVE_CYCLE),
+    (SPAN_SERVE_DISPATCH, SPAN_SERVE_CYCLE),
+    (SPAN_SERVE_GUARD, SPAN_SERVE_DISPATCH),
+    (SPAN_SERVE_META, SPAN_SERVE_DISPATCH),
+    (SPAN_SERVE_LAUNCH, SPAN_SERVE_DISPATCH),
+    (SPAN_MANAGER_DRAIN, SPAN_SERVE_CYCLE),
+    (SPAN_SCHEDULER_FLUSH, SPAN_MANAGER_DRAIN),
+    (SPAN_SERVE_FINISH, SPAN_SERVE_CYCLE),
+    (SPAN_SERVE_GAUGES, SPAN_SERVE_CYCLE),
+    (SPAN_SERVE_FINALIZE, SPAN_SERVE_CALL),
+    (SPAN_SERVE_SUBMIT, None),
+)
+_NO_SPAN = contextlib.nullcontext()
 
 
 class Histogram:
@@ -713,12 +766,18 @@ class SpanLedger:
             return
         sp.phase(name, self.tel.cycle, self.tel.trace.now_us())
 
-    def close(self, sp: Optional[RequestSpan], outcome: str) -> None:
+    def close(self, sp: Optional[RequestSpan], outcome: str,
+              cycle: Optional[int] = None) -> None:
         """Terminal transition: fold the span into the ledger, feed the
-        latency histograms, emit its Perfetto track."""
+        latency histograms, emit its Perfetto track.  ``cycle`` (default:
+        the current drain cycle) is the cycle the request finished in; the
+        wall-clock end is always now — the continuous driver closes a
+        finished request when its tokens reach the host, stamped with the
+        cycle its row left."""
         if sp is None or sp.outcome is not None:
             return
-        cycle = self.tel.cycle
+        if cycle is None:
+            cycle = self.tel.cycle
         trace = self.tel.trace
         sp.finish(outcome, cycle, trace.now_us())
         self._open.pop(sp.sid, None)
@@ -824,6 +883,16 @@ class Telemetry:
         if self.manager is None:
             return 0
         return self.manager.scheduler._cycle
+
+    def span(self, name: str, **args: Any):
+        """Host span ``guardian.<name>`` (one of :data:`HOST_SPANS`) as a
+        ``jax.profiler.TraceAnnotation``: recorded only while a profiler
+        trace runs, one annotation enter and exit otherwise.  A shared
+        no-op context when telemetry is off.  ``args`` go on cycle-level
+        spans only (cycle, rows, request ids)."""
+        if not self.enabled:
+            return _NO_SPAN
+        return TraceAnnotation(HOST_SPAN_PREFIX + name, **args)
 
     def event(self, name: str, track: str,
               dur_us: Optional[float] = None,

@@ -57,13 +57,27 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.core.arena import PagePool
+from repro.core.arena import PagePool, PoolArena
 from repro.core.elastic import ElasticError, ResizeEvent
 from repro.core.fence import FenceParams, FencePolicy
 from repro.core.manager import GuardianManager
 from repro.core.partition import OutOfArenaMemory
 from repro.core.quarantine import QuarantinePolicy, TenantState
 from repro.core.scheduler import round_robin_interleave
+from repro.core.telemetry import (
+    SPAN_SERVE_CALL,
+    SPAN_SERVE_CYCLE,
+    SPAN_SERVE_DISPATCH,
+    SPAN_SERVE_FINALIZE,
+    SPAN_SERVE_FINISH,
+    SPAN_SERVE_GAUGES,
+    SPAN_SERVE_GUARD,
+    SPAN_SERVE_JOIN,
+    SPAN_SERVE_LAUNCH,
+    SPAN_SERVE_LEAVE,
+    SPAN_SERVE_META,
+    SPAN_SERVE_SUBMIT,
+)
 from repro.core.violations import NUM_KINDS, ViolationKind
 from repro.launch.compile_cache import place_compile_cache
 from repro.launch.steps import (
@@ -152,6 +166,9 @@ class _ContState:
     prefills: int = 0
     decodes: int = 0
     served: List[int] = dataclasses.field(default_factory=list)
+    #: (RequestSpan, drain cycle its row left) of served requests: each
+    #: closes in _cont_finalize, when its tokens reach the host
+    finished: List[tuple] = dataclasses.field(default_factory=list)
 
 
 def make_shared_manager(n_engines: int, max_batch: int = 8,
@@ -326,6 +343,19 @@ class ServeEngine:
             # co-hosted engine; virt space = the manager's page-granular
             # slot space, phys space = the pool tensor's page axis
             self._pool.pages = PagePool(self._n_phys, n_slots)
+        tel = self.manager.telemetry
+        if paged and tel.enabled:
+            # the pool as allocated, against the logical KV of one token
+            # at the configuration's dtype (kv_token_cycles_live counts
+            # the tokens): shapes only, no device read
+            tel.registry.set_gauge("kv_pool_bytes", sum(
+                leaf.nbytes for a in self.manager.arenas.values()
+                if isinstance(a, PoolArena)
+                for leaf in jax.tree.leaves(a.buf)))
+            tel.registry.set_gauge(
+                "kv_bytes_per_token",
+                2 * cfg.decoder_layers * cfg.n_kv_heads * cfg.head_dim
+                * jnp.dtype(cfg.dtype).itemsize)
         self.rejected: List[int] = []     # rids dropped by quarantine
         self._requests: List[Request] = []
         #: rid -> RequestSpan handle (absent entirely when telemetry is
@@ -528,6 +558,11 @@ class ServeEngine:
         paged mode no slot is claimed here — virtual pages are allocated
         when the request joins a batch row, so a queued request costs
         nothing until it runs."""
+        with self.manager.telemetry.span(SPAN_SERVE_SUBMIT):
+            return self._submit(tenant, prompt, max_new, arrive)
+
+    def _submit(self, tenant: str, prompt: np.ndarray,
+                max_new: Optional[int], arrive: int) -> int:
         self.manager.quarantine.check_admission(tenant, "submit")
         part = self.manager.bounds.lookup(tenant)
         # a manager-registered tenant becomes this engine's to serve (and
@@ -887,10 +922,18 @@ class ServeEngine:
                           nxt=jnp.zeros((B,), jnp.int32),
                           default_new=max_new_tokens)
 
+    def _cont_held(self, st: _ContState) -> int:
+        """Rows that stay through this cycle's :meth:`_cont_leave`."""
+        return sum(1 for i, r in enumerate(st.rows)
+                   if r is not None and st.left[i] > 0
+                   and self._admissible(r.tenant))
+
     def _cont_leave(self, st: _ContState) -> None:
         """Cycle boundary: rows whose request exhausted its budget (or
         whose tenant lost admissibility) leave — their virtual pages
-        return to the tenant's free pool immediately."""
+        return to the tenant's free pool immediately.  A served request's
+        span closes when its tokens reach the host (:meth:`_cont_finalize`),
+        stamped with this cycle."""
         tel = self.manager.telemetry
         for i, r in enumerate(st.rows):
             if r is None:
@@ -906,7 +949,9 @@ class ServeEngine:
                 r.done = True
                 st.served.append(r.rid)
                 st.rows[i] = None
-                tel.spans.close(self._spans.pop(r.rid, None), "complete")
+                sp = self._spans.pop(r.rid, None)
+                if sp is not None:
+                    st.finished.append((sp, tel.cycle))
 
     def _cont_join(self, st: _ContState) -> List[int]:
         """Refill idle rows from the admission queue (FIFO, gated on the
@@ -1018,6 +1063,7 @@ class ServeEngine:
         continuers = [i for i, r in enumerate(st.rows)
                       if r is not None and i not in set(joiners)]
         tel = self.manager.telemetry
+        rids = {}
         if tel.enabled:
             for i in joiners:
                 tel.spans.phase(self._spans.get(st.rows[i].rid),
@@ -1025,40 +1071,58 @@ class ServeEngine:
             for i in continuers:
                 tel.spans.phase(self._spans.get(st.rows[i].rid),
                                 "decode")
+            rids = {step: " ".join(str(st.rows[i].rid) for i in rows)
+                    for step, rows in (("prefill", joiners),
+                                       ("decode", continuers)) if rows}
         pre_req = dec_req = None
         plen = 0
-        if joiners:
-            # per-step guard: rows parked for THIS step fence to the
-            # engine's scratch extent (whose virtual ids resolve to the
-            # garbage page) — fencing them with their tenant's extent
-            # would wrap the scratch ids INTO the tenant's live pages
-            guard = self._guard_for_rows(
-                [r if i in set(joiners) else None
-                 for i, r in enumerate(st.rows)])
-            plen = max(len(st.rows[i].prompt) for i in joiners)
-            toks = np.zeros((self.max_batch, plen), np.int32)
-            for i in joiners:
-                toks[i, :len(st.rows[i].prompt)] = st.rows[i].prompt
-            pre_req = self._client.launch_kernel(
-                self._steps.prefill_name,
-                args=(self.params, self._cont_meta(st, set(joiners)),
-                      {"tokens": jnp.asarray(toks)}, guard))
-            st.prefills += 1
-        if continuers:
-            guard = self._guard_for_rows(
-                [r if i in set(continuers) else None
-                 for i, r in enumerate(st.rows)])
-            meta = self._cont_meta(st, set(continuers))
-            if self._sample_key is not None:
-                self._sample_key, sub = jax.random.split(self._sample_key)
-                x = (st.nxt, sub)
-            else:
-                x = st.nxt
-            dec_req = self._client.launch_kernel(
-                self._steps.decode_name,
-                args=(self.params, meta, x, guard))
-            st.decodes += 1
-            self.decode_steps += 1
+        with tel.span(SPAN_SERVE_DISPATCH, **rids):
+            if joiners:
+                # per-step guard: rows parked for THIS step fence to the
+                # engine's scratch extent (whose virtual ids resolve to
+                # the garbage page) — fencing them with their tenant's
+                # extent would wrap the scratch ids INTO the tenant's
+                # live pages
+                with tel.span(SPAN_SERVE_GUARD):
+                    guard = self._guard_for_rows(
+                        [r if i in set(joiners) else None
+                         for i, r in enumerate(st.rows)])
+                plen = max(len(st.rows[i].prompt) for i in joiners)
+                toks = np.zeros((self.max_batch, plen), np.int32)
+                for i in joiners:
+                    toks[i, :len(st.rows[i].prompt)] = st.rows[i].prompt
+                with tel.span(SPAN_SERVE_META):
+                    meta = self._cont_meta(st, set(joiners))
+                batch = {"tokens": jnp.asarray(toks)}
+                with tel.span(SPAN_SERVE_LAUNCH):
+                    pre_req = self._client.launch_kernel(
+                        self._steps.prefill_name,
+                        args=(self.params, meta, batch, guard))
+                st.prefills += 1
+                if tel.enabled:
+                    tel.registry.inc("prefill_tokens_useful", sum(
+                        len(st.rows[i].prompt) for i in joiners))
+                    tel.registry.inc("prefill_tokens_computed",
+                                     self.max_batch * plen)
+            if continuers:
+                with tel.span(SPAN_SERVE_GUARD):
+                    guard = self._guard_for_rows(
+                        [r if i in set(continuers) else None
+                         for i, r in enumerate(st.rows)])
+                with tel.span(SPAN_SERVE_META):
+                    meta = self._cont_meta(st, set(continuers))
+                if self._sample_key is not None:
+                    self._sample_key, sub = jax.random.split(
+                        self._sample_key)
+                    x = (st.nxt, sub)
+                else:
+                    x = st.nxt
+                with tel.span(SPAN_SERVE_LAUNCH):
+                    dec_req = self._client.launch_kernel(
+                        self._steps.decode_name,
+                        args=(self.params, meta, x, guard))
+                st.decodes += 1
+                self.decode_steps += 1
         return pre_req, dec_req, joiners, continuers, plen
 
     def _cont_finish(self, st: _ContState, pre_req, dec_req,
@@ -1122,8 +1186,13 @@ class ServeEngine:
         if pages is not None:
             tel.registry.set_gauge("pool_page_occupancy",
                                    pages.occupancy())
+        # tokens the active rows hold in the pool, once per cycle: over
+        # serve_cycles x kv_pool_bytes, the share of the pool in use
+        tel.registry.inc("kv_token_cycles_live", int(sum(
+            st.lens[i] for i, r in enumerate(st.rows) if r is not None)))
 
     def _cont_finalize(self, st: _ContState) -> Dict[int, List[int]]:
+        self._cont_leave(st)       # the rows the last cycle finished
         self._in_run = False
         self._apply_pending_scrubs()
         if st.trail:
@@ -1134,6 +1203,11 @@ class ServeEngine:
                 for i, rid in enumerate(owners):
                     if rid is not None and rid in by_rid:
                         by_rid[rid].generated.append(int(toks[c, i]))
+        # delivered: a served request's wall-clock latency ends here
+        tel = self.manager.telemetry
+        for sp, cycle in st.finished:
+            tel.spans.close(sp, "complete", cycle=cycle)
+        st.finished.clear()
         by_rid = {r.rid: r for r in self._requests}
         return {rid: by_rid[rid].generated for rid in st.served
                 if rid in by_rid}
@@ -1209,28 +1283,49 @@ def serve_continuous(engines: List["ServeEngine"],
     if not all(e.paged for e in engines):
         raise ValueError("serve_continuous drives paged engines; slab "
                          "engines lockstep through serve_engines")
-    states = [e._cont_begin(max_new_tokens) for e in engines]
+    tel = mgr.telemetry
+    with tel.span(SPAN_SERVE_CALL):
+        states = [e._cont_begin(max_new_tokens) for e in engines]
+        try:
+            _cont_cycles(engines, states, mgr)
+            with tel.span(SPAN_SERVE_FINALIZE):
+                return [e._cont_finalize(st)
+                        for e, st in zip(engines, states)]
+        finally:
+            for e in engines:
+                e._in_run = False
+
+
+def _cont_cycles(engines: List["ServeEngine"], states: List[_ContState],
+                 mgr: GuardianManager) -> None:
+    """The drain cycles of one :func:`serve_continuous` call, until no
+    engine holds a row or a waiting request.  Counts ``serve_cycles`` (the
+    cycles that dispatched) and the host CPU time of their bodies
+    (``serve_host_cpu_ns``: thread time, so a host blocked in dispatch by
+    the runtime's in-flight limit does not read as host work)."""
+    tel = mgr.telemetry
     stalled = 0
-    try:
-        while True:
+    while True:
+        held = sum(e._cont_held(st) for e, st in zip(engines, states))
+        if not held and not any(e._cont_waiting(st)
+                                for e, st in zip(engines, states)):
+            return          # _cont_finalize retires the last rows
+        with tel.span(SPAN_SERVE_CYCLE, cycle=tel.cycle, rows=held):
+            cpu0 = time.thread_time_ns() if tel.enabled else 0
             handles = []
-            busy = False
             dispatched = False
             eligible_waiting = False
             for e, st in zip(engines, states):
-                e._cont_leave(st)
-                joiners = e._cont_join(st)
+                with tel.span(SPAN_SERVE_LEAVE):
+                    e._cont_leave(st)
+                with tel.span(SPAN_SERVE_JOIN):
+                    joiners = e._cont_join(st)
                 handles.append((e, st) + e._cont_dispatch(st, joiners))
                 if handles[-1][2] is not None or handles[-1][3] is not None:
                     dispatched = True
-                if any(r is not None for r in st.rows) \
-                        or e._cont_waiting(st):
-                    busy = True
                 eligible_waiting = eligible_waiting or any(
                     not r.done and not r.pages and r.arrive <= st.cycles
                     and e._admissible(r.tenant) for r in e._requests)
-            if not busy:
-                break
             # eligible requests exist but nothing could join or run for
             # several consecutive cycles: every tenant is page-full with
             # no active rows to free them — fail loudly, don't spin
@@ -1242,12 +1337,14 @@ def serve_continuous(engines: List["ServeEngine"],
                     "tenant can allocate pages (extents too small?)")
             mgr.run_queued()
             for e, st, pre, dec, joiners, continuers, plen in handles:
-                e._cont_finish(st, pre, dec, joiners, continuers, plen)
-                e._cont_gauges(st)
-        return [e._cont_finalize(st) for e, st in zip(engines, states)]
-    finally:
-        for e in engines:
-            e._in_run = False
+                with tel.span(SPAN_SERVE_FINISH):
+                    e._cont_finish(st, pre, dec, joiners, continuers, plen)
+                with tel.span(SPAN_SERVE_GAUGES):
+                    e._cont_gauges(st)
+            if dispatched and tel.enabled:
+                tel.registry.inc("serve_cycles")
+                tel.registry.inc("serve_host_cpu_ns",
+                                 time.thread_time_ns() - cpu0)
 
 
 def _pow2(n: int) -> int:

@@ -34,6 +34,26 @@ from repro.models.hybrid import HybridCache
 from repro.optim import adamw, apply_updates, cosine
 
 
+#: names of the served steps.  A jitted step compiles as ``jit_<name>``
+#: (``jit_fused_<name>`` when the scheduler fuses engines) and its
+#: operations sit under ``jax.named_scope(<name>)``: the benchmark's
+#: device-trace readers find the steps by these names.
+PREFILL_STEP = "prefill_step"
+DECODE_STEP = "decode_step"
+
+
+def _named_step(name: str) -> Callable[[Callable], Callable]:
+    """Give a step function the stable ``name`` (``__name__``, which jit
+    turns into the program's name) and scope its body under it."""
+    def wrap(fn: Callable) -> Callable:
+        def step(*args):
+            with jax.named_scope(name):
+                return fn(*args)
+        step.__name__ = step.__qualname__ = name
+        return step
+    return wrap
+
+
 @dataclasses.dataclass
 class StepBundle:
     fn: Callable
@@ -372,6 +392,7 @@ def build_trusted_serve_steps(api: ModelAPI, pool_key: str,
     """
     sk = step_key or pool_key
 
+    @_named_step(PREFILL_STEP)
     def prefill_step(arena, pool, params, meta, batch, guard):
         cache = join_cache_pool(pool, meta)
         cache, logits = api.prefill(params, cache, batch, guard=guard)
@@ -380,6 +401,7 @@ def build_trusted_serve_steps(api: ModelAPI, pool_key: str,
             new_meta, jnp.argmax(logits, -1).astype(jnp.int32))
 
     if temperature > 0:
+        @_named_step(DECODE_STEP)
         def decode_step(arena, pool, params, meta, x, guard):
             toks, key = x
             cache = join_cache_pool(pool, meta)
@@ -393,6 +415,7 @@ def build_trusted_serve_steps(api: ModelAPI, pool_key: str,
             return arena, new_pool, (new_meta, nxt.astype(jnp.int32))
         decode_name = f"serve.decode.sampled[{sk}:t{temperature}:k{top_k}]"
     else:
+        @_named_step(DECODE_STEP)
         def decode_step(arena, pool, params, meta, toks, guard):
             cache = join_cache_pool(pool, meta)
             cache, logits = api.decode(params, cache, toks, guard=guard)
@@ -520,6 +543,7 @@ def build_prefill_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
     rules = make_rules(mesh, fsdp=False)   # serving: weights TP-only
     guard = make_guard(cfg, shape, policy, guard_enabled)
 
+    @_named_step(PREFILL_STEP)
     def prefill_step(params, cache, batch):
         cache, logits = api.prefill(params, cache, batch, guard=guard,
                                     rules=rules)
@@ -559,6 +583,7 @@ def build_decode_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
     rules = make_rules(mesh, fsdp=False)
     guard = make_guard(cfg, shape, policy, guard_enabled)
 
+    @_named_step(DECODE_STEP)
     def decode_step(params, cache, tokens):
         cache, logits = api.decode(params, cache, tokens, guard=guard,
                                    rules=rules)
