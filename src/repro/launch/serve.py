@@ -286,21 +286,25 @@ class ServeEngine:
         # model shape share one KV pool — globally-partitioned slot ids
         # address it directly through the shared fence table, and the
         # per-engine footprint does not grow with the engine count.
+        # The pool holds the model's dtype: K and V are computed in it,
+        # so a wider pool would store nothing more and move twice the bytes.
+        kv_dtype = jnp.dtype(cfg.dtype)
         if paged:
-            # Geometry-only pool key: the global page pool is one
+            # Geometry-and-dtype pool key: the global page pool is one
             # (L, P, page, KH, D) tensor — engines serving *different*
-            # model shapes with the same KV geometry share it (and the
-            # manager drain), keeping model identity in the step key so
-            # their step symbols stay distinct.
+            # model shapes with the same KV geometry and dtype share it
+            # (and the manager drain), keeping model identity in the step
+            # key so their step symbols stay distinct.
             self._n_phys = _pow2(n_slots)
             pool_key = (f"paged:L{cfg.decoder_layers}:kh{cfg.n_kv_heads}:"
                         f"d{cfg.head_dim}:pg{KV.PAGE_SIZE}:"
-                        f"P{self._n_phys}:f32")
+                        f"P{self._n_phys}:{kv_dtype.name}")
             step_key = (f"{cfg.name}:{cfg.family}:{cfg.n_layers}x"
                         f"{cfg.d_model}v{cfg.vocab}:{pool_key}")
         else:
             pool_key = (f"{cfg.name}:{cfg.family}:{cfg.n_layers}x"
-                        f"{cfg.d_model}v{cfg.vocab}:s{n_slots}:l{max_len}")
+                        f"{cfg.d_model}v{cfg.vocab}:s{n_slots}:l{max_len}:"
+                        f"{kv_dtype.name}")
             step_key = None
         self._steps = build_trusted_serve_steps(
             self.api, pool_key, step_key=step_key,
@@ -314,13 +318,13 @@ class ServeEngine:
         if paged:
             cache = KV.init_global_kv_cache(
                 cfg, max_batch, max_len, 1 if adopted else self._n_phys,
-                dtype=jnp.float32)
+                dtype=kv_dtype)
         elif cfg.family == "ssm":
             cache = self.api.init_cache(max_batch,
                                         slots=1 if adopted else n_slots)
         else:
             cache = self.api.init_cache(max_batch, max_len,
-                                        dtype=jnp.float32,
+                                        dtype=kv_dtype,
                                         slots=1 if adopted else n_slots)
         pool, self._meta = split_cache_pool(cache)
         self._client = self.manager.register_tenant(self.engine_tenant,

@@ -132,12 +132,15 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, *,
 def init_global_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
                          total_pages: int, *,
                          page_size: int = PAGE_SIZE,
-                         dtype=jnp.float32,
+                         dtype=None,
                          n_layers: Optional[int] = None) -> PagedKVCache:
     """Global paged pool: one ``(L, total_pages, page, KH, D)`` tensor
     shared by every tenant; each batch row carries ``max_len //
-    page_size`` virtual page ids (see module docstring, layout 2)."""
+    page_size`` virtual page ids (see module docstring, layout 2).  The
+    pool holds ``dtype``, by default the model's: K and V are computed in
+    it, so a wider pool stores nothing more."""
     L = n_layers if n_layers is not None else cfg.decoder_layers
+    dtype = jnp.dtype(cfg.dtype if dtype is None else dtype)
     pages_per_req = max(max_len // page_size, 1)
     shape = (L, total_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     return PagedKVCache(
@@ -175,12 +178,18 @@ def gather_layer_kv(cache: PagedKVCache, layer: jax.Array,
     from repro.distributed.sharding import constrain
     if cache.global_paged:
         phys = _fenced_phys_pages(cache, cache.page_table, guard)  # (B,P)
-        k_l = jax.lax.dynamic_index_in_dim(cache.k, layer, axis=0,
-                                           keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(cache.v, layer, axis=0,
-                                           keepdims=False)
-        k_p = jnp.take(k_l, phys, axis=0)      # (B, P, page, KH, D)
-        v_p = jnp.take(v_l, phys, axis=0)
+        # one gather at (layer, page) over the (L * P_total) page rows —
+        # a bitcast of the pool — so no op materialises a layer slice:
+        # only the pages the rows address are read.  The clip keeps an
+        # unfenced id inside its layer (fenced ids are in range already),
+        # so the take needs no out-of-range fill.
+        L, P_total = cache.k.shape[:2]
+        rows = (jnp.asarray(layer, jnp.int32) * P_total
+                + jnp.clip(phys, 0, P_total - 1))
+        k_p = jnp.take(cache.k.reshape(L * P_total, *cache.k.shape[2:]),
+                       rows, axis=0, mode="clip")
+        v_p = jnp.take(cache.v.reshape(L * P_total, *cache.v.shape[2:]),
+                       rows, axis=0, mode="clip")
         B, P, page, KH, D = k_p.shape
         return (k_p.reshape(B, P * page, KH, D),
                 v_p.reshape(B, P * page, KH, D))
