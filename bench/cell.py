@@ -31,6 +31,12 @@ CHECK_BLOCKS = 16
 #: no drain cycle of these cells is shorter: it bounds how many calls a
 #: window can make, so the warm-up knows which token trails to compile
 MIN_CYCLE_S = 0.004
+#: a traced window closes at the first call boundary past this many decode
+#: steps, which the TPU's device trace is shown to hold whole (PERF.md);
+#: past about 1,800 steps of stablelm3b it drops events.  A block with more
+#: operations a step holds fewer: the traced result's ``device`` gives
+#: ``decode_steps`` and ``decode_steps_traced``, which differ when it did
+TRACE_DECODE_STEPS = 1200
 
 
 class NoChip(RuntimeError):
@@ -92,11 +98,7 @@ def program_config(cfg: Config):
     pc = get_config(cfg.model.arch)
     if cfg.raw.get("program_reduced"):
         pc = pc.reduced()
-    m = cfg.model
-    want = dict(n_layers=m.layers, d_model=m.d_model, n_heads=m.heads,
-                n_kv_heads=m.kv_heads, head_dim=m.head_dim, d_ff=m.d_ff,
-                vocab=m.vocab, tie_embeddings=m.tied, norm=m.norm,
-                dtype=m.dtype, rope_theta=m.rope_theta)
+    want = cfg.block.program_fields(cfg.model)
     got = {k: getattr(pc, k) for k in want}
     if got != want:
         raise ValueError(f"program config {pc.name} {got} differs from "
@@ -242,19 +244,35 @@ def check_blocks(sample: List[Served], max_len: int):
     return blocks
 
 
-def widest_gap(m, weights, blocks, quant: Optional[str] = None) -> float:
-    """Widest gap by which a chosen token's reference logit lies below the
-    reference's best.  With ``quant`` the tokens are those the
-    lower-precision control puts first at the same positions."""
-    from bench import reference
+def gaps(ref_logits, tokens):
+    """How far each chosen token's reference logit lies below the
+    reference's best at that position."""
+    import jax.numpy as jnp
 
+    chosen = jnp.take_along_axis(ref_logits, jnp.asarray(tokens)[..., None],
+                                 -1)[..., 0]
+    return jnp.max(ref_logits, -1) - chosen
+
+
+def argmax(logits):
+    import jax.numpy as jnp
+
+    return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+def widest_gap(block, m, weights, blocks,
+               quant: Optional[str] = None) -> float:
+    """Widest gap by which a chosen token's reference logit (the block's
+    ``logits_at``) lies below the reference's best.  With ``quant`` the
+    tokens are those the lower-precision control puts first at the same
+    positions."""
     worst = 0.0
     for toks, read, chosen, mask in blocks:
         if quant is not None:
-            chosen = np.asarray(reference.argmax(
-                reference.logits_at(m, weights, toks, read, quant=quant)))
-        ref = reference.logits_at(m, weights, toks, read)
-        g = np.asarray(reference.gaps(ref, np.asarray(chosen)))
+            chosen = np.asarray(argmax(
+                block.logits_at(m, weights, toks, read, quant=quant)))
+        ref = block.logits_at(m, weights, toks, read)
+        g = np.asarray(gaps(ref, np.asarray(chosen)))
         worst = max(worst, float(g[mask].max()))
         del ref
     return worst
@@ -283,6 +301,11 @@ class Context:
     served: List[Served]
     decode_steps: int
     trace: object = None
+
+    @property
+    def block(self):
+        """The configuration's block: its counts (``bench/blocks``)."""
+        return self.cfg.block
 
     def decode_ctxs(self):
         """Context length of every decoded token: the request's own prompt
@@ -360,15 +383,15 @@ def run(bench: Dict, workload: str, seed: int, seconds: float,
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     compiles = _compile_counter()
 
-    from bench import weights as weights_mod
+    from bench.weights import seed_key
 
-    m = cfg.model
-    key = weights_mod.seed_key(seed)
+    m, block = cfg.model, cfg.block
+    key = seed_key(seed)
     holder = {}
 
     def make(like):
-        w = holder["w"] = weights_mod.make_weights(m, key)
-        return weights_mod.to_program_tree(m, w, like)
+        w = holder["w"] = block.make_weights(m, key)
+        return block.to_program_tree(m, w, like)
 
     mgr, eng = build_engine(cfg, make)
     traffic = traffic_mod.Traffic(mix, cfg, seed)
@@ -411,7 +434,8 @@ def run(bench: Dict, workload: str, seed: int, seconds: float,
     t0 = time.perf_counter()
     k = 0
     with annotate("bench.window"):
-        while k == 0 or time.perf_counter() - t0 < seconds:
+        while k == 0 or (time.perf_counter() - t0 < seconds and not (
+                traced and eng.decode_steps - steps0 >= TRACE_DECODE_STEPS)):
             served.extend(serve_call(eng, traffic.batch(k), annotate))
             k += 1
     t1 = time.perf_counter()
@@ -436,7 +460,8 @@ def run(bench: Dict, workload: str, seed: int, seconds: float,
                  or not all(0 <= t < m.vocab for t in s.out))
     sample = pick_sample(served, seed)
     blocks = check_blocks(sample, cfg.max_len) if sample else []
-    gap = widest_gap(m, weights, blocks) if sample else float("inf")
+    gap = widest_gap(block, m, weights, blocks) if sample \
+        else float("inf")
     compared = sum(len(s.out) for s in sample)
     log(f"check: {len(sample)} requests, {compared} served tokens compared")
     checks, correct = judge(gap, failed, limits)
@@ -460,8 +485,14 @@ def run(bench: Dict, workload: str, seed: int, seconds: float,
         busiest = sorted(summary.modules.items(), key=lambda kv: -sum(kv[1]))
         log("trace programs: " + ", ".join(
             f"{n} x{len(d)} {sum(d):.3f}s" for n, d in busiest[:6]))
+        held = summary.module_seconds("decode_step")[0]
+        log(f"trace holds {held} of {decode_steps} decode steps")
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
+        # a trace that dropped events shows here, beside the readers
+        # that then read nothing
+        device["decode_steps"] = decode_steps
+        device["decode_steps_traced"] = held
         peaks = load_peaks(dev.device_kind) if require_tpu else \
             {"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0}
         ctx = Context(model=m, cfg=cfg, peaks=peaks, served=served,
@@ -478,7 +509,7 @@ def run(bench: Dict, workload: str, seed: int, seconds: float,
     result["device"] = device
     if control:
         c_checks, c_correct = judge(
-            widest_gap(m, weights, blocks, quant="fp8") if sample
+            widest_gap(block, m, weights, blocks, quant="fp8") if sample
             else float("inf"), failed, limits)
         result["control"] = {"correct": c_correct, "checks": c_checks}
     result["checks"] = checks

@@ -1,97 +1,31 @@
-"""Operations and bytes of the served dense decoder, from its shapes.
-
-Counts are what the algorithm needs, whatever implements it: weights at
-the dtype they are served in and read once per step, and the logical KV
-cache at bfloat16 and the logical head_dim, of the tokens a request really
-attends (pads not counted).  A multiply-add is two operations.
-``pool_bytes`` is the exception: it is the float32 page pool as the
-program allocates it, lanes padded to 128, for the memory budget.
+"""The served pool's geometry, and the decode step's least time from a
+configuration's block's counts (``bench/blocks``: operations and bytes of
+the algorithm, from the shapes).
 """
 
 from __future__ import annotations
 
-from bench.model import Model
-
 PAGE_SIZE = 64          # tokens per KV page of the served pool
 LANES = 128             # a TPU vector register's minor dimension
-KV_BYTES = 2            # logical KV element: bfloat16
 
 
-def _itemsize(dtype: str) -> int:
-    return {"bfloat16": 2, "float32": 4}[dtype]
-
-
-def layer_matmul_params(m: Model) -> int:
-    q = m.heads * m.head_dim
-    kv = m.kv_heads * m.head_dim
-    return m.d_model * (q + 2 * kv) + q * m.d_model + 3 * m.d_model * m.d_ff
-
-
-def head_params(m: Model) -> int:
-    return m.d_model * m.vocab
-
-
-def weight_bytes_per_step(m: Model) -> int:
-    """Weights one step reads: every layer matrix and the output head
-    (the tied table doubles as the head), plus float32 norm parameters.
-    The embedding rows a step gathers are a few KiB and not counted."""
-    norms = (2 * m.layers + 1) * m.d_model * (2 if m.norm == "layernorm"
-                                              else 1)
-    mats = m.layers * layer_matmul_params(m) + head_params(m)
-    return mats * _itemsize(m.dtype) + norms * 4
-
-
-def kv_bytes_per_token(m: Model) -> int:
-    return m.layers * 2 * m.kv_heads * m.head_dim * KV_BYTES
-
-
-def pool_bytes(m: Model, pages: int) -> int:
-    """The program's float32 K and V page pools, head_dim padded to 128."""
-    lanes = -(-m.head_dim // LANES) * LANES
-    return 2 * m.layers * pages * PAGE_SIZE * m.kv_heads * lanes * 4
-
-
-def attention_flops(m: Model, queries: int, keys: int) -> int:
-    """QK^T and PV for ``queries`` rows each attending ``keys`` keys, all
-    layers.  For causal prefill pass the summed key counts as ``keys``
-    with ``queries=1``."""
-    return 4 * m.layers * m.heads * m.head_dim * queries * keys
-
-
-def decode_token_flops(m: Model, ctx: int) -> int:
-    """One decoded token attending ``ctx`` cached tokens (itself included)."""
-    return 2 * (m.layers * layer_matmul_params(m) + head_params(m)) \
-        + attention_flops(m, 1, ctx)
-
-
-def decode_token_bytes(m: Model, ctx: int) -> int:
-    """KV one decoded token reads (``ctx`` tokens) and writes (one)."""
-    return kv_bytes_per_token(m) * (ctx + 1)
-
-
-def prefill_flops(m: Model, prompt: int) -> int:
-    """A prompt of ``prompt`` tokens, logits at its last position only."""
-    causal_keys = prompt * (prompt + 1) // 2
-    return 2 * m.layers * layer_matmul_params(m) * prompt \
-        + 2 * head_params(m) + attention_flops(m, 1, causal_keys)
-
-
-def decode_least_seconds(m: Model, steps: int, ctxs, peak_flops: float,
+def decode_least_seconds(block, m, steps: int, ctxs, peak_flops: float,
                          hbm_bytes_per_s: float) -> float:
     """Least time of ``steps`` decode steps that together decode one token
     for each context length in ``ctxs``.  It is the larger of all their
     operations over the peak and all their bytes over the bandwidth: the
     exact sum of each step's own bound while every step is bound by the
     same resource, which ``memory_bound_always`` checks."""
-    flops = sum(decode_token_flops(m, c) for c in ctxs)
-    nbytes = steps * weight_bytes_per_step(m) \
-        + sum(decode_token_bytes(m, c) for c in ctxs)
+    flops = sum(block.decode_token_flops(m, c) for c in ctxs)
+    nbytes = steps * block.weight_bytes_per_step(m) \
+        + sum(block.decode_token_bytes(m, c) for c in ctxs)
     return max(flops / peak_flops, nbytes / hbm_bytes_per_s)
 
 
-def memory_bound_always(m: Model, max_batch: int, max_len: int,
+def memory_bound_always(block, m, max_batch: int, max_len: int,
                         peak_flops: float, hbm_bytes_per_s: float) -> bool:
     """Whether a decode step of ``max_batch`` rows at the longest context
     still takes longer to read its weights than to compute."""
-    most = max_batch * decode_token_flops(m, max_len)
-    return most / peak_flops < weight_bytes_per_step(m) / hbm_bytes_per_s
+    most = max_batch * block.decode_token_flops(m, max_len)
+    return most / peak_flops < block.weight_bytes_per_step(m) \
+        / hbm_bytes_per_s

@@ -1,11 +1,12 @@
 """A model configuration, read from ``bench/configs/<name>.json``.
 
-The file holds the published ``config.json`` keys with their published
-values; ``departures`` gives the values the program runs instead (the
-run, and the reference with it, takes the published keys overlaid with
-them; ``departure_notes`` says why).  A ``serve`` block gives the serving
-shape: rows, context, tenants; ``reduced`` names the serving keys cut for
-the chip and ``cuts`` gives the reason for each.
+The file names its ``block``, the module ``bench/blocks/<block>.py`` that
+reads it, and holds the published ``config.json`` keys with their
+published values; ``departures`` gives the values the program runs
+instead (the run, and the reference with it, takes the published keys
+overlaid with them; ``departure_notes`` says why).  A ``serve`` block
+gives the serving shape: rows, context, tenants; ``reduced`` names the
+serving keys cut for the chip and ``cuts`` gives the reason for each.
 """
 
 from __future__ import annotations
@@ -13,34 +14,12 @@ from __future__ import annotations
 import dataclasses
 import json
 import pathlib
-from typing import Dict, Tuple
+import types
+from typing import Any, Dict, Tuple
+
+from bench import blocks
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
-
-
-@dataclasses.dataclass(frozen=True)
-class Model:
-    """The dense decoder a configuration file describes."""
-
-    name: str
-    arch: str
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    norm: str                 # "layernorm" (with bias) or "rmsnorm"
-    norm_eps: float
-    rope_fraction: float      # share of head_dim that rotates
-    rope_theta: float
-    tied: bool
-    dtype: str                # dtype the weights are served in
-
-    @property
-    def rope_dims(self) -> int:
-        return int(self.head_dim * self.rope_fraction) // 2 * 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,7 +31,8 @@ class Tenant:
 
 @dataclasses.dataclass(frozen=True)
 class Config:
-    model: Model
+    model: Any                # the block's model
+    block: types.ModuleType   # bench/blocks/<block>.py
     max_batch: int
     max_len: int
     tenants: Tuple[Tenant, ...]
@@ -63,42 +43,21 @@ class Config:
         return self.model.name
 
 
-#: published keys the dense block here does not model; a configuration
-#: runs only where its departures set them to null
-UNMODELLED = ("scale_emb", "scale_depth", "dim_model_base")
-
-
 def parse(raw: Dict) -> Config:
     """The configuration as it runs: the published keys overlaid with the
-    file's ``departures``."""
+    file's ``departures``, read by the block the file names."""
+    if "block" not in raw:
+        path = BENCH_DIR / "configs" / f"{raw.get('name')}.json"
+        raise LookupError(f"{path} names no block: its \"block\" key names "
+                          "the file bench/blocks/<block>.py that reads it")
+    block = blocks.load(raw["block"])
     raw = {**raw, **raw.get("departures", {})}
-    hidden = raw["hidden_size"]
-    heads = raw["num_attention_heads"]
-    if "norm_eps" in raw:
-        norm, eps = "layernorm", raw["norm_eps"]
-    else:
-        norm, eps = "rmsnorm", raw["rms_norm_eps"]
-    dtypes = {"bfloat16": "bfloat16", "float32": "float32"}
-    model = Model(
-        name=raw["name"], arch=raw["arch"],
-        layers=raw["num_hidden_layers"], d_model=hidden, heads=heads,
-        kv_heads=raw.get("num_key_value_heads", heads),
-        head_dim=raw.get("head_dim", hidden // heads),
-        d_ff=raw["intermediate_size"], vocab=raw["vocab_size"],
-        norm=norm, norm_eps=float(eps),
-        rope_fraction=float(raw.get("rope_pct", 1.0)),
-        rope_theta=float(raw["rope_theta"]),
-        tied=bool(raw["tie_word_embeddings"]),
-        dtype=dtypes[raw["torch_dtype"]])
-    if raw["hidden_act"] != "silu" or raw.get("use_qkv_bias", False) \
-            or any(raw.get(k) is not None for k in UNMODELLED):
-        raise ValueError(f"{model.name}: only SwiGLU blocks without a qkv "
-                         f"bias or any of {UNMODELLED} are modelled")
     serve = raw["serve"]
     tenants = tuple(Tenant(t["name"], t["pages"], t["popularity"])
                     for t in serve["tenants"])
-    return Config(model=model, max_batch=serve["max_batch"],
-                  max_len=serve["max_len"], tenants=tenants, raw=raw)
+    return Config(model=block.parse(raw), block=block,
+                  max_batch=serve["max_batch"], max_len=serve["max_len"],
+                  tenants=tenants, raw=raw)
 
 
 def load(name: str) -> Config:

@@ -4,8 +4,9 @@
         --seconds 30 --trace 0
 
 From the root of a checkout.  The cells, metrics and bounds are in
-``BENCHMARK.json``; a cell's configuration, traffic mix, limits and
-per-layer readers are files under ``bench/`` found by name.  The last
+``BENCHMARK.json``; a cell's configuration, the block it names
+(``bench/blocks``), its traffic mix, limits and per-layer readers are
+files under ``bench/`` found by name.  The last
 line of standard output is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the end-to-end metrics with ``--trace 0``, the
 per-layer ones with ``--trace 1``), ``device``, with ``--trace 1``

@@ -6,7 +6,7 @@ import re
 
 import pytest
 
-from bench import cell, model, traffic
+from bench import blocks, cell, model, traffic
 from bench.model import BENCH_DIR
 
 BENCH = json.loads((BENCH_DIR.parent / "BENCHMARK.json").read_text())
@@ -36,6 +36,22 @@ def test_configs_name_their_files():
         assert sorted(raw["reduced"]) == sorted(c["reduced"])
         assert sorted(raw["cuts"]) == sorted(raw["reduced"])
         model.parse(raw)
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_a_config_names_a_block_that_provides_the_interface(config):
+    raw = json.loads((BENCH_DIR.parent / config["file"]).read_text())
+    assert (blocks.BLOCKS_DIR / f"{raw['block']}.py").is_file()
+    block = blocks.load(raw["block"])
+    assert model.parse(raw).block is block
+    for name in blocks.INTERFACE:
+        assert callable(getattr(block, name)), name
+    # no default: a missing key or a block with no file fails at load
+    with pytest.raises(LookupError, match=r"bench/blocks/nope\.py"):
+        model.parse(dict(raw, block="nope"))
+    with pytest.raises(LookupError, match=rf"{config['name']}\.json names "
+                       "no block"):
+        model.parse({k: v for k, v in raw.items() if k != "block"})
 
 
 def test_departures_overlay_the_published_values():

@@ -1,95 +1,17 @@
-"""The benchmark's random weights, made on the device from the seed in one
-jitted call, in the dtype they are served in, and handed to the program.
+"""The key the benchmark's random weights are made from.
 
-The program under test makes weights of its own when an engine is built;
-the benchmark replaces them with these, so that the reference computes
-with weights the program did not make.  ``to_program_tree`` is the one
-place that knows the program's parameter layout, and it refuses a tree
-whose structure, shapes or dtypes differ.
+A configuration's block makes the weights from it (``make_weights``, on
+the device in one jitted call, in the dtype they are served in) and hands
+them to the program in its parameter tree (``to_program_tree``), so that
+the reference computes with weights the program did not make.
 """
 
 from __future__ import annotations
 
-import functools
-import math
-from typing import Dict
-
 import jax
-import jax.numpy as jnp
-
-from bench.model import Model
-
-NORM_SPREAD = 0.1       # norm scales 1 + N(0, 0.1), biases N(0, 0.1)
 
 
 def seed_key(seed: int) -> jax.Array:
     """A PRNG key for any seed up to 2**62 (seeds may exceed 32 bits)."""
     seed = int(seed)
     return jax.random.fold_in(jax.random.PRNGKey(seed % 2**31), seed >> 31)
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def make_weights(m: Model, key: jax.Array) -> Dict[str, jax.Array]:
-    """Matrices N(0, 1/fan_in) (output projections further scaled by
-    1/sqrt(2 layers)), embedding N(0, 0.02), norms near identity."""
-    dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[m.dtype]
-    L, d, ff, V = m.layers, m.d_model, m.d_ff, m.vocab
-    q, kv = m.heads * m.head_dim, m.kv_heads * m.head_dim
-    out_scale = 1.0 / math.sqrt(2 * L)
-    shapes = {
-        "wq": ((L, d, q), 1.0), "wk": ((L, d, kv), 1.0),
-        "wv": ((L, d, kv), 1.0), "wo": ((L, q, d), out_scale),
-        "wg": ((L, d, ff), 1.0), "wu": ((L, d, ff), 1.0),
-        "wd": ((L, ff, d), out_scale),
-    }
-    if not m.tied:
-        shapes["head"] = ((d, V), 1.0)
-    keys = iter(jax.random.split(key, len(shapes) + 8))
-    w = {}
-    for name, (shape, scale) in shapes.items():
-        std = scale / math.sqrt(shape[-2])
-        w[name] = (jax.random.normal(next(keys), shape, jnp.float32)
-                   * std).astype(dt)
-    w["embed"] = (jax.random.normal(next(keys), (V, d), jnp.float32)
-                  * 0.02).astype(dt)
-    for name, shape in (("norm1", (L, d)), ("norm2", (L, d)),
-                        ("norm_f", (d,))):
-        w[name + ".scale"] = 1.0 + NORM_SPREAD * jax.random.normal(
-            next(keys), shape, jnp.float32)
-        if m.norm == "layernorm":
-            w[name + ".bias"] = NORM_SPREAD * jax.random.normal(
-                next(keys), shape, jnp.float32)
-    return w
-
-
-def to_program_tree(m: Model, w: Dict[str, jax.Array], like) -> Dict:
-    """The weights in the program's parameter tree (``like`` is the tree a
-    ``ServeEngine`` built); shares the arrays, copies nothing."""
-
-    def norm(name):
-        p = {"scale": w[name + ".scale"]}
-        if m.norm == "layernorm":
-            p["bias"] = w[name + ".bias"]
-        return p
-
-    embed = {"table": w["embed"]}
-    if not m.tied:
-        embed["head"] = w["head"]
-    tree = {
-        "embed": embed,
-        "layers": {
-            "attn": {k: w[k] for k in ("wq", "wk", "wv", "wo")},
-            "mlp": {k: w[k] for k in ("wg", "wu", "wd")},
-            "norm1": norm("norm1"),
-            "norm2": norm("norm2"),
-        },
-        "norm_f": norm("norm_f"),
-    }
-    want = jax.tree.structure(like)
-    if jax.tree.structure(tree) != want:
-        raise ValueError(f"program parameter tree changed: {want}")
-    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(like)):
-        if a.shape != b.shape or a.dtype != b.dtype:
-            raise ValueError(f"program parameter {b.shape} {b.dtype} "
-                             f"!= benchmark {a.shape} {a.dtype}")
-    return tree
