@@ -1,9 +1,12 @@
 from repro.configs.base import (
+    FULL,
     SHAPES,
+    SLIDING,
     ModelConfig,
     MoEConfig,
     SSMConfig,
     ShapeConfig,
+    YarnConfig,
     get_config,
     list_archs,
     register,
@@ -11,6 +14,7 @@ from repro.configs.base import (
 )
 
 __all__ = [
-    "SHAPES", "ModelConfig", "MoEConfig", "SSMConfig", "ShapeConfig",
-    "get_config", "list_archs", "register", "shape_applicable",
+    "FULL", "SHAPES", "SLIDING", "ModelConfig", "MoEConfig", "SSMConfig",
+    "ShapeConfig", "YarnConfig", "get_config", "list_archs", "register",
+    "shape_applicable",
 ]

@@ -22,10 +22,41 @@ from typing import Dict, Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int            # experts the router scores
     top_k: int
     d_ff_expert: int            # per-expert FFN width
     router_jitter: float = 0.0
+    #: experts whose weights this chip holds, ``expert_base`` onwards (0:
+    #: all of them).  Under expert parallelism each chip holds a share of
+    #: every layer's bank: the router still scores all ``num_experts``,
+    #: and a token's pairs with experts held elsewhere add nothing here.
+    experts_held: int = 0
+    expert_base: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+#: layer kinds of ``ModelConfig.layer_types``
+SLIDING = "sliding"
+FULL = "full"
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as ``transformers``'
+    ``_compute_yarn_parameters`` reads it: inverse frequencies blended
+    between interpolation by ``factor`` and extrapolation over a ramp set
+    by ``beta_fast``/``beta_slow`` rotations within
+    ``original_max_position_embeddings``; cos and sin are scaled by
+    ``attention_factor``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +92,11 @@ class ModelConfig:
     # remaining layers are Mamba2. ssm family: alternate sLSTM/mLSTM.
     attn_every: int = 0                  # 0 = all attention (dense)
     attn_window: int = 0                 # sliding-window size; 0 = full
+    #: per-layer kind, ``SLIDING`` or ``FULL`` (moe family): ``attn_window``
+    #: applies to sliding layers only, ``yarn`` to full layers only.  Empty:
+    #: every layer alike, windowed wherever ``attn_window`` is set.
+    layer_types: Tuple[str, ...] = ()
+    yarn: Optional[YarnConfig] = None
     # encoder-decoder (seamless-m4t)
     enc_layers: int = 0
     cross_attention: bool = False
@@ -94,6 +130,26 @@ class ModelConfig:
     def decoder_layers(self) -> int:
         return self.n_layers
 
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Every layer's kind (``layer_types``, or the one kind they share)."""
+        if self.layer_types:
+            return tuple(self.layer_types)
+        return (SLIDING if self.attn_window else FULL,) * self.n_layers
+
+    @property
+    def layer_period(self) -> Tuple[str, ...]:
+        """The shortest run of kinds that ``layer_kinds`` repeats."""
+        kinds = self.layer_kinds
+        n = len(kinds)
+        for p in range(1, n + 1):
+            if n % p == 0 and kinds == kinds[:p] * (n // p):
+                return kinds[:p]
+        return kinds
+
+    def window_of(self, kind: str) -> int:
+        return self.attn_window if kind == SLIDING else 0
+
     def param_count(self) -> int:
         """Analytic parameter count (for roofline MODEL_FLOPS = 6·N·D)."""
         d, v = self.d_model, self.vocab
@@ -112,7 +168,7 @@ class ModelConfig:
             return self.param_count()
         d = self.d_model
         full = self.param_count()
-        all_experts = self.n_layers * self.moe.num_experts * 3 * d * \
+        all_experts = self.n_layers * self.moe.held * 3 * d * \
             self.moe.d_ff_expert
         active = self.n_layers * self.moe.top_k * 3 * d * self.moe.d_ff_expert
         return full - all_experts + active
@@ -143,7 +199,7 @@ class ModelConfig:
         norms = 2 * d
         if self.family == "moe":
             router = d * self.moe.num_experts
-            experts = self.moe.num_experts * 3 * d * self.moe.d_ff_expert
+            experts = self.moe.held * 3 * d * self.moe.d_ff_expert
             return self._attn_params() + router + experts + norms
         if self.family == "hybrid":
             # per-layer average: mamba2 block + amortized shared attn block
@@ -159,10 +215,11 @@ class ModelConfig:
 
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
+        period = self.layer_period if self.layer_types else ()
         kw: Dict = dict(
             name=self.name + "-smoke",
             family=self.family,
-            n_layers=min(self.n_layers, 2),
+            n_layers=len(period) if period else min(self.n_layers, 2),
             d_model=64,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 4) or 2,
@@ -177,6 +234,10 @@ class ModelConfig:
             mrope=self.mrope,
             attn_every=min(self.attn_every, 2),
             attn_window=min(self.attn_window, 32) if self.attn_window else 0,
+            layer_types=period,
+            yarn=dataclasses.replace(self.yarn,
+                                     original_max_position_embeddings=16)
+            if self.yarn else None,
             enc_layers=min(self.enc_layers, 2),
             cross_attention=self.cross_attention,
             frontend_stub=self.frontend_stub,
@@ -184,7 +245,11 @@ class ModelConfig:
             source=self.source,
         )
         if self.moe:
-            kw["moe"] = MoEConfig(num_experts=4, top_k=2, d_ff_expert=32)
+            # a held share keeps its ratio: half of the 4 experts at most
+            held = 0 if self.moe.held == self.moe.num_experts else \
+                max(1, min(2, 4 * self.moe.held // self.moe.num_experts))
+            kw["moe"] = MoEConfig(num_experts=4, top_k=2, d_ff_expert=32,
+                                  experts_held=held)
         if self.ssm:
             kw["ssm"] = SSMConfig(state_dim=8, conv_width=4, expand=2,
                                   chunk=8)
@@ -253,6 +318,7 @@ def _ensure_loaded():
     from repro.configs import (  # noqa: F401
         grok_1_314b,
         llama3_405b,
+        mellum2_12b_a2p5b,
         minicpm_2b,
         qwen15_32b,
         qwen2_vl_2b,

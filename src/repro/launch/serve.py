@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import FULL, SLIDING, get_config
 from repro.core.arena import PagePool, PoolArena
 from repro.core.elastic import ElasticError, ResizeEvent
 from repro.core.fence import FenceParams, FencePolicy
@@ -96,6 +96,10 @@ from repro.models.guard import GuardSpec
 #: Engines sharing a manager suffix it (``__scratch.e1``, ...) so each
 #: engine gets its own scratch partition and launch queue.
 ENGINE_TENANT = "__scratch"
+#: per-tenant counters of the keys attended in sliding-window layers and
+#: in full layers, from the rows' lengths (``ServeEngine._count_keys``)
+KEYS_WINDOW = "attn.keys_window"
+KEYS_FULL = "attn.keys_full"
 
 
 @dataclasses.dataclass
@@ -161,6 +165,9 @@ class _ContState:
     #: on device until _cont_finalize materializes the whole trail in one
     #: transfer
     trail: List[tuple] = dataclasses.field(default_factory=list)
+    #: per-step (counts (B, len(api.counters)) device array, row-owner
+    #: tenants), fetched with the trail
+    counts: List[tuple] = dataclasses.field(default_factory=list)
     default_new: int = 16
     cycles: int = 0
     prefills: int = 0
@@ -300,15 +307,17 @@ class ServeEngine:
                         f"d{cfg.head_dim}:pg{KV.PAGE_SIZE}:"
                         f"P{self._n_phys}:{kv_dtype.name}")
             step_key = (f"{cfg.name}:{cfg.family}:{cfg.n_layers}x"
-                        f"{cfg.d_model}v{cfg.vocab}:{pool_key}")
+                        f"{cfg.d_model}v{cfg.vocab}{_kinds_key(cfg)}:"
+                        f"{pool_key}")
         else:
             pool_key = (f"{cfg.name}:{cfg.family}:{cfg.n_layers}x"
-                        f"{cfg.d_model}v{cfg.vocab}:s{n_slots}:l{max_len}:"
-                        f"{kv_dtype.name}")
+                        f"{cfg.d_model}v{cfg.vocab}{_kinds_key(cfg)}:"
+                        f"s{n_slots}:l{max_len}:{kv_dtype.name}")
             step_key = None
         self._steps = build_trusted_serve_steps(
             self.api, pool_key, step_key=step_key,
-            temperature=temperature, top_k=top_k)
+            temperature=temperature, top_k=top_k,
+            counts=self.manager.telemetry.enabled)
         # A later co-hosted engine adopts the already-registered pool:
         # build its cache with a single-slot pool instead (the meta half —
         # slot ids, seq lens, page tables — is slot-count independent), so
@@ -367,6 +376,12 @@ class ServeEngine:
         self._spans: Dict[int, Any] = {}
         self._rid = 0
         self.decode_steps = 0
+        kinds = cfg.layer_kinds
+        #: (counter, layers of the kind, window) for _count_keys
+        self._key_counters = [
+            (name, kinds.count(kind), window) for name, kind, window in (
+                (KEYS_WINDOW, SLIDING, cfg.attn_window),
+                (KEYS_FULL, FULL, 0)) if kinds.count(kind)]
         #: sampled decode steps thread a fresh PRNG key per cycle
         self._sample_key = jax.random.PRNGKey(seed ^ 0x5EED) \
             if temperature > 0 else None
@@ -680,7 +695,7 @@ class ServeEngine:
                 vocab=FenceParams(base=0, size=_pow2(self.cfg.vocab)),
                 kv=slot_params,
                 expert=(FenceParams(base=0, size=_pow2(
-                    self.cfg.moe.num_experts)) if self.cfg.moe else None),
+                    self.cfg.moe.held)) if self.cfg.moe else None),
                 page=FenceParams(base=0, size=self._n_phys),
                 row_policy=row_policy,
                 # a snapshot: the host rewrites the map in place at later
@@ -700,7 +715,7 @@ class ServeEngine:
             kv=slot_params,
             state=slot_params,
             expert=(FenceParams(base=0, size=_pow2(
-                self.cfg.moe.num_experts)) if self.cfg.moe else None),
+                self.cfg.moe.held)) if self.cfg.moe else None),
             page=FenceParams(base=0, size=_pow2(max(pages, 1))),
             row_policy=row_policy,
         )
@@ -828,7 +843,7 @@ class ServeEngine:
         """Consume the drained step's result; True while decodes remain.
         The step sampled on device — no logits, no host sync here (the
         pool half of the cache was committed by the manager)."""
-        st.meta, st.nxt = req.result
+        st.meta, st.nxt = req.result[:2]
         if st.batch is not None:
             st.batch = None            # prefilled; decodes follow
             return st.remaining > 0
@@ -1108,6 +1123,8 @@ class ServeEngine:
                         len(st.rows[i].prompt) for i in joiners))
                     tel.registry.inc("prefill_tokens_computed",
                                      self.max_batch * plen)
+                    for i in joiners:
+                        self._count_keys(st.rows[i].tenant, 0, plen)
             if continuers:
                 with tel.span(SPAN_SERVE_GUARD):
                     guard = self._guard_for_rows(
@@ -1127,23 +1144,45 @@ class ServeEngine:
                         args=(self.params, meta, x, guard))
                 st.decodes += 1
                 self.decode_steps += 1
+                if tel.enabled:
+                    for i in continuers:
+                        self._count_keys(st.rows[i].tenant,
+                                         int(st.lens[i]),
+                                         int(st.lens[i]) + 1)
         return pre_req, dec_req, joiners, continuers, plen
+
+    def _count_keys(self, tenant: str, start: int, stop: int) -> None:
+        """Count the keys that queries at positions [start, stop) attend,
+        in the sliding and in the full layers (every position is written,
+        pads too)."""
+        for name, layers, window in self._key_counters:
+            self.manager.telemetry.registry.inc(
+                name, layers * (_keys_upto(stop, window)
+                                - _keys_upto(start, window)),
+                tenant=tenant)
 
     def _cont_finish(self, st: _ContState, pre_req, dec_req,
                      joiners: List[int], continuers: List[int],
                      plen: int) -> None:
         """Merge the cycle's results on device (no host sync), record the
         emitted tokens, advance budgets/lens."""
+        for req, rows in ((pre_req, joiners), (dec_req, continuers)):
+            if req is not None and len(req.result) > 2:
+                # the step's per-row counts stay on device, like the
+                # tokens, until _cont_finalize
+                st.counts.append((req.result[2], tuple(
+                    st.rows[i].tenant if i in rows else None
+                    for i in range(self.max_batch))))
         if pre_req is not None and dec_req is not None:
-            _, pre_nxt = pre_req.result
-            _, dec_nxt = dec_req.result
+            pre_nxt = pre_req.result[1]
+            dec_nxt = dec_req.result[1]
             mask = np.zeros((self.max_batch,), bool)
             mask[joiners] = True
             nxt = jnp.where(jnp.asarray(mask), pre_nxt, dec_nxt)
         elif pre_req is not None:
-            _, nxt = pre_req.result
+            nxt = pre_req.result[1]
         elif dec_req is not None:
-            _, nxt = dec_req.result
+            nxt = dec_req.result[1]
         else:
             st.cycles += 1
             return
@@ -1209,6 +1248,18 @@ class ServeEngine:
                         by_rid[rid].generated.append(int(toks[c, i]))
         # delivered: a served request's wall-clock latency ends here
         tel = self.manager.telemetry
+        if st.counts:
+            # no stack: a list transfers without a program of its own
+            got = jax.device_get([c for c, _ in st.counts])
+            per: Dict[str, np.ndarray] = {}
+            for counts, (_, owners) in zip(got, st.counts):
+                for i, tenant in enumerate(owners):
+                    if tenant is not None:
+                        per[tenant] = per.get(tenant, 0) + counts[i]
+            for tenant, total in per.items():
+                for name, n in zip(self.api.counters, total):
+                    tel.registry.inc(name, int(n), tenant=tenant)
+        st.counts.clear()
         for sp, cycle in st.finished:
             tel.spans.close(sp, "complete", cycle=cycle)
         st.finished.clear()
@@ -1349,6 +1400,28 @@ def _cont_cycles(engines: List["ServeEngine"], states: List[_ContState],
                 tel.registry.inc("serve_cycles")
                 tel.registry.inc("serve_host_cpu_ns",
                                  time.thread_time_ns() - cpu0)
+
+
+def _kinds_key(cfg) -> str:
+    """The step-key part that tells layer patterns and expert shares
+    apart: empty for a model whose layers are all alike and hold every
+    expert."""
+    key = ""
+    if cfg.layer_types:
+        key += ":" + "".join(k[0] for k in cfg.layer_period) + \
+            f"w{cfg.attn_window}" + ("y" if cfg.yarn else "")
+    if cfg.moe and cfg.moe.held != cfg.moe.num_experts:
+        key += f":e{cfg.moe.expert_base}+{cfg.moe.held}of" \
+            f"{cfg.moe.num_experts}"
+    return key
+
+
+def _keys_upto(n: int, window: int) -> int:
+    """Keys that queries at positions 0..n-1 attend under causal attention
+    over the last ``window`` positions (0: all of them)."""
+    if not window or n <= window:
+        return n * (n + 1) // 2
+    return window * (window + 1) // 2 + (n - window) * window
 
 
 def _pow2(n: int) -> int:

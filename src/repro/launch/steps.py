@@ -365,14 +365,17 @@ def build_trusted_serve_steps(api: ModelAPI, pool_key: str,
                               verify: bool = False,
                               step_key: Optional[str] = None,
                               temperature: float = 0.0,
-                              top_k: int = 0) -> TrustedStepBundle:
+                              top_k: int = 0,
+                              counts: bool = False) -> TrustedStepBundle:
     """Trusted prefill/decode step functions for one model API.
 
     The step rebuilds the cache from the manager-threaded pool + the
     engine's meta operand, runs the model, and splits the result back.
     Sampling happens *inside* the step: the engine's decode loop stays
-    fully asynchronous — per step it receives ``(meta, next_ids)`` and
-    never materializes the ``(B, vocab)`` logits on the host.
+    fully asynchronous — per step it receives ``(meta, next_ids)`` (and,
+    with ``counts`` for a model with ``api.counters``, its per-row counts
+    as a third item) and never materializes the ``(B, vocab)`` logits on
+    the host.
 
     ``pool_key`` must identify the pool geometry (slot count, page
     layout); ``step_key`` (default: ``pool_key``) additionally carries
@@ -391,37 +394,49 @@ def build_trusted_serve_steps(api: ModelAPI, pool_key: str,
     names.
     """
     sk = step_key or pool_key
+    kw = {}
+    if counts and api.counters:
+        kw = {"counts": True}
+        sk += ":counts"
+
+    def run(model_fn, params, cache, x, guard):
+        """The model's step; its per-row counts, if any, ride beside."""
+        out = model_fn(params, cache, x, guard=guard, **kw)
+        return out[0], out[1], out[2:]
 
     @_named_step(PREFILL_STEP)
     def prefill_step(arena, pool, params, meta, batch, guard):
         cache = join_cache_pool(pool, meta)
-        cache, logits = api.prefill(params, cache, batch, guard=guard)
+        cache, logits, extra = run(api.prefill, params, cache, batch, guard)
         new_pool, new_meta = split_cache_pool(cache)
         return arena, new_pool, (
-            new_meta, jnp.argmax(logits, -1).astype(jnp.int32))
+            new_meta, jnp.argmax(logits, -1).astype(jnp.int32), *extra)
 
     if temperature > 0:
         @_named_step(DECODE_STEP)
         def decode_step(arena, pool, params, meta, x, guard):
             toks, key = x
             cache = join_cache_pool(pool, meta)
-            cache, logits = api.decode(params, cache, toks, guard=guard)
+            cache, logits, extra = run(api.decode, params, cache, toks,
+                                       guard)
             new_pool, new_meta = split_cache_pool(cache)
             logits = logits.astype(jnp.float32)
             if top_k:
                 kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
                 logits = jnp.where(logits < kth, -jnp.inf, logits)
             nxt = jax.random.categorical(key, logits / temperature, axis=-1)
-            return arena, new_pool, (new_meta, nxt.astype(jnp.int32))
+            return arena, new_pool, (new_meta, nxt.astype(jnp.int32),
+                                     *extra)
         decode_name = f"serve.decode.sampled[{sk}:t{temperature}:k{top_k}]"
     else:
         @_named_step(DECODE_STEP)
         def decode_step(arena, pool, params, meta, toks, guard):
             cache = join_cache_pool(pool, meta)
-            cache, logits = api.decode(params, cache, toks, guard=guard)
+            cache, logits, extra = run(api.decode, params, cache, toks,
+                                       guard)
             new_pool, new_meta = split_cache_pool(cache)
             return arena, new_pool, (
-                new_meta, jnp.argmax(logits, -1).astype(jnp.int32))
+                new_meta, jnp.argmax(logits, -1).astype(jnp.int32), *extra)
         decode_name = f"serve.decode[{sk}]"
 
     return TrustedStepBundle(

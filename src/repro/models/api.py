@@ -9,6 +9,10 @@
     prefill(params, cache, batch) -> (cache, last_logits)
     decode(params, cache, tokens) -> (cache, logits)
     batch_specs(shape)            -> ShapeDtypeStruct dict for the batch
+    counters                      -> names of the per-row counts that
+                                     prefill/decode append as a third
+                                     output, (B, len(counters)) int32,
+                                     when called with ``counts=True``
 
 ``batch_specs`` is the assignment's ``input_specs()``: weak-type-correct,
 shardable stand-ins for every model input, no device allocation.
@@ -46,6 +50,7 @@ class ModelAPI:
     prefill: Callable
     decode: Callable
     batch_specs: Callable
+    counters: tuple = ()
 
 
 def _tok_spec(b, s):
@@ -54,6 +59,11 @@ def _tok_spec(b, s):
 
 def get_model(cfg: ModelConfig) -> ModelAPI:
     fam = cfg.family
+    if (cfg.layer_types or cfg.yarn) and fam != "moe":
+        # the other families would serve every layer alike, with plain RoPE
+        raise ValueError(
+            f"layer_types and yarn are honoured by the moe family only, "
+            f"not by {fam!r}")
     if fam in ("dense", "vlm"):
         return _dense_api(cfg) if fam == "dense" else _vlm_api(cfg)
     if fam == "moe":
@@ -107,6 +117,7 @@ def _moe_api(cfg: ModelConfig) -> ModelAPI:
             cfg, params, cache, batch["tokens"], **kw),
         decode=lambda params, cache, tokens, **kw: MOE.decode(
             cfg, params, cache, tokens, **kw),
+        counters=MOE.PAIR_COUNTS,
     )
 
 

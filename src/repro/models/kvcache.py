@@ -167,6 +167,65 @@ def _fenced_phys_pages(cache: PagedKVCache, table: jax.Array,
     return fence_pages(guard, virt)
 
 
+def _take_pages(cache: PagedKVCache, layer: jax.Array,
+                phys: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Global layout: the pages ``phys`` (B, n) of one layer of K and V,
+    each (B, n, page, KH, D)."""
+    # one gather at (layer, page) over the (L * P_total) page rows — a
+    # bitcast of the pool — so no op materialises a layer slice: only the
+    # pages the rows address are read.  The clip keeps an unfenced id
+    # inside its layer (fenced ids are in range already), so the take
+    # needs no out-of-range fill.
+    L, P_total = cache.k.shape[:2]
+    rows = (jnp.asarray(layer, jnp.int32) * P_total
+            + jnp.clip(phys, 0, P_total - 1))
+    k_p = jnp.take(cache.k.reshape(L * P_total, *cache.k.shape[2:]),
+                   rows, axis=0, mode="clip")
+    v_p = jnp.take(cache.v.reshape(L * P_total, *cache.v.shape[2:]),
+                   rows, axis=0, mode="clip")
+    return k_p, v_p
+
+
+def _histories(k_p: jax.Array, v_p: jax.Array
+               ) -> Tuple[jax.Array, jax.Array]:
+    """(B, n, page, KH, D) pages -> (B, n * page, KH, D) histories."""
+    B, P, page, KH, D = k_p.shape
+    return (k_p.reshape(B, P * page, KH, D),
+            v_p.reshape(B, P * page, KH, D))
+
+
+def window_pages(cache: PagedKVCache, window: int) -> int:
+    """Pages that hold any ``window`` consecutive positions, at most the
+    row's page table."""
+    return min(-(-window // cache.page_size) + 1, cache.page_table.shape[1])
+
+
+def gather_window_kv(cache: PagedKVCache, layer: jax.Array,
+                     kv_len: jax.Array, window: int,
+                     guard: Optional[GuardSpec] = None
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Global layout: each row's history of one layer, read only from the
+    ``window_pages`` pages that hold its last ``window`` of ``kv_len``
+    (B,) positions (``window`` 0: every page of its table).  Returns k, v
+    (B, n * page, KH, D) and the position of their first key (B,)."""
+    P = cache.page_table.shape[1]
+    n = window_pages(cache, window) if window else P
+    if n == P:
+        first = jnp.zeros_like(kv_len)
+        table = cache.page_table
+    else:
+        first = jnp.clip((kv_len - window) // cache.page_size, 0, P - n)
+        logical = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+        table = jnp.take_along_axis(cache.page_table, logical, axis=1)
+    pages = _take_pages(cache, layer, _fenced_phys_pages(cache, table, guard))
+    # the barrier keeps the attention's preferred layout of the history
+    # from reaching back through the gather: at a pool whose page tile is
+    # (KH, D), as Mellum2's (4, 128), the TPU compiler otherwise relayouts
+    # the whole pool for every gather, not the pages gathered
+    k, v = _histories(*jax.lax.optimization_barrier(pages))
+    return k, v, first * cache.page_size
+
+
 def gather_layer_kv(cache: PagedKVCache, layer: jax.Array,
                     guard: Optional[GuardSpec] = None,
                     rules=None) -> Tuple[jax.Array, jax.Array]:
@@ -178,21 +237,7 @@ def gather_layer_kv(cache: PagedKVCache, layer: jax.Array,
     from repro.distributed.sharding import constrain
     if cache.global_paged:
         phys = _fenced_phys_pages(cache, cache.page_table, guard)  # (B,P)
-        # one gather at (layer, page) over the (L * P_total) page rows —
-        # a bitcast of the pool — so no op materialises a layer slice:
-        # only the pages the rows address are read.  The clip keeps an
-        # unfenced id inside its layer (fenced ids are in range already),
-        # so the take needs no out-of-range fill.
-        L, P_total = cache.k.shape[:2]
-        rows = (jnp.asarray(layer, jnp.int32) * P_total
-                + jnp.clip(phys, 0, P_total - 1))
-        k_p = jnp.take(cache.k.reshape(L * P_total, *cache.k.shape[2:]),
-                       rows, axis=0, mode="clip")
-        v_p = jnp.take(cache.v.reshape(L * P_total, *cache.v.shape[2:]),
-                       rows, axis=0, mode="clip")
-        B, P, page, KH, D = k_p.shape
-        return (k_p.reshape(B, P * page, KH, D),
-                v_p.reshape(B, P * page, KH, D))
+        return _histories(*_take_pages(cache, layer, phys))
     slots = fence(guard, "kv", cache.slot_ids)            # (B,)
     pages = fence(guard, "page", cache.page_table)        # (B,P)
     k_l = jax.lax.dynamic_index_in_dim(cache.k, layer, axis=0,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import FULL, ModelConfig
 from repro.core.fence import guarded_take
 from repro.models.guard import GuardSpec, fence
 
@@ -99,13 +99,41 @@ def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D); positions: (B, S) int32."""
+def yarn_freqs(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies (``transformers``' ``yarn`` rope type,
+    correction range truncated to whole dimensions): each frequency is
+    blended from its interpolated (``/ factor``) and extrapolated value
+    over a linear ramp between the dimensions that turn ``beta_fast`` and
+    ``beta_slow`` times within the original context."""
+    def dim_of(rotations):
+        return head_dim * math.log(yarn.original_max_position_embeddings
+                                   / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(head_dim, theta)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp                 # share that extrapolates
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: (B, S, H, D); positions: (B, S) int32.  With ``yarn``, the YaRN
+    frequencies, and cos and sin scaled by its attention factor."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta), jnp.float32)      # (D/2,)
+    inv = rope_freqs(d, theta) if yarn is None else yarn_freqs(d, theta,
+                                                               yarn)
+    freqs = jnp.asarray(inv, jnp.float32)                       # (D/2,)
     ang = positions.astype(jnp.float32)[..., None] * freqs      # (B,S,D/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        cos = cos * yarn.attention_factor
+        sin = sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)
@@ -408,10 +436,13 @@ def decode_attention(
     kv_len: jax.Array,             # (B,)
     *,
     window: int = 0,
+    kv_start: Optional[jax.Array] = None,   # (B,) position of k[:, 0]
 ) -> jax.Array:
     """Single-token attention over a (gathered) KV history.
 
     One un-chunked pass: scores are (B, H, 1, Skv) — linear in Skv, fine.
+    ``kv_start`` places a history that begins past position 0 (a window's
+    pages).
     """
     B, _, H, D = q.shape
     KH = k.shape[2]
@@ -419,10 +450,12 @@ def decode_attention(
     scale = 1.0 / math.sqrt(D)
     qg = q.reshape(B, 1, KH, G, D)
     s = _gqa_scores(qg, k) * scale           # (B,KH,G,1,Skv)
-    pos = jnp.arange(k.shape[1], dtype=jnp.int32)
-    mask = pos[None, :] < kv_len[:, None]     # (B,Skv)
+    pos = jnp.arange(k.shape[1], dtype=jnp.int32)[None, :]
+    if kv_start is not None:
+        pos = pos + kv_start[:, None]
+    mask = pos < kv_len[:, None]              # (B,Skv)
     if window:
-        mask = mask & (pos[None, :] > kv_len[:, None] - 1 - window)
+        mask = mask & (pos > kv_len[:, None] - 1 - window)
     s = jnp.where(mask[:, None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v,
@@ -488,8 +521,9 @@ def out_proj(cfg: ModelConfig, p: Params, o: jax.Array) -> jax.Array:
     return o.reshape(B, S, cfg.q_dim) @ p["wo"]
 
 
-def positions_rope(cfg: ModelConfig, q, k, positions):
-    """Apply (M-)RoPE to q,k given positions ((B,S) or (B,S,3))."""
+def positions_rope(cfg: ModelConfig, q, k, positions, kind: str = ""):
+    """Apply (M-)RoPE to q,k given positions ((B,S) or (B,S,3)).  A full
+    layer (``kind``) of a configuration with ``yarn`` rotates by YaRN."""
     if cfg.mrope:
         secs = mrope_sections(cfg.head_dim)
         if positions.ndim == 2:  # text-only: all three components equal
@@ -499,8 +533,9 @@ def positions_rope(cfg: ModelConfig, q, k, positions):
     else:
         if positions.ndim == 3:
             positions = positions[..., 0]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        yarn = cfg.yarn if kind == FULL else None
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
     return q, k
 
 

@@ -1,28 +1,36 @@
-"""MoE decoder LM — grok-1-314b (8e top-2) / qwen3-moe-30b-a3b (128e top-8).
+"""MoE decoder LM — grok-1-314b (8e top-2) / qwen3-moe-30b-a3b (128e top-8)
+/ mellum2-12b-a2.5b (64e top-8, sliding and full layers).
 
 Expert parallelism: expert tensors carry an ``expert`` logical axis mapped
-to the mesh ``model`` axis; dispatch uses the capacity-based one-hot einsum
-formulation so GSPMD inserts the all-to-alls.  The **router's expert ids are
-data-dependent** — the Guardian "expert" fence is applied to them before
-they form dispatch offsets, so a corrupted/adversarial router can never
-address another tenant's expert-buffer rows (the MoE analogue of the
-paper's fenced ld/st).
+to the mesh ``model`` axis; training dispatch uses the capacity-based
+one-hot einsum formulation so GSPMD inserts the all-to-alls.  A chip may
+hold a share of each layer's experts (``MoEConfig.experts_held``): the
+router scores every expert, and only the pairs with held experts are
+computed here.  Serving is dropless (:func:`moe_dropless`).  The
+**router's expert ids are data-dependent** — the Guardian "expert" fence
+is applied to them before they form dispatch offsets, so a
+corrupted/adversarial router can never address another tenant's
+expert-buffer rows (the MoE analogue of the paper's fenced ld/st).
+
+Layers of different kinds (``ModelConfig.layer_types``) run as a scan over
+the periods of the kind pattern with each period unrolled, so every
+layer's window and rotary table stay static.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import FULL, SLIDING, ModelConfig
 from repro.distributed.sharding import ShardingRules, constrain
 from repro.models import layers as L
 from repro.models import kvcache as KV
-from repro.models import transformer as TF
 from repro.models.guard import GuardSpec, fence
 
 Params = Dict[str, Any]
@@ -33,23 +41,24 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 def moe_init(key, cfg: ModelConfig) -> Params:
+    """The router over every expert and the bank of the experts held
+    here, the bank drawn in the served dtype."""
     m = cfg.moe
-    d, e, f = cfg.d_model, m.num_experts, m.d_ff_expert
+    d, e, f = cfg.d_model, m.held, m.d_ff_expert
     kr, kg, ku, kd = jax.random.split(key, 4)
     std = 1.0 / math.sqrt(d)
     out_std = 1.0 / math.sqrt(f * 2 * cfg.n_layers)
     dt = L.dtype_of(cfg)
     p: Params = {
-        "router": (jax.random.normal(kr, (d, e), jnp.float32) * std
-                   ).astype(jnp.float32),
-        "wu": (jax.random.normal(ku, (e, d, f), jnp.float32) * std
-               ).astype(dt),
-        "wd": (jax.random.normal(kd, (e, f, d), jnp.float32) * out_std
-               ).astype(dt),
+        "router": (jax.random.normal(kr, (d, m.num_experts), jnp.float32)
+                   * std).astype(jnp.float32),
+        "wu": jax.random.normal(ku, (e, d, f), dt) * jnp.asarray(std, dt),
+        "wd": jax.random.normal(kd, (e, f, d), dt) * jnp.asarray(out_std,
+                                                                 dt),
     }
     if cfg.act == "silu":
-        p["wg"] = (jax.random.normal(kg, (e, d, f), jnp.float32) * std
-                   ).astype(dt)
+        p["wg"] = jax.random.normal(kg, (e, d, f), dt) * jnp.asarray(std,
+                                                                     dt)
     return p
 
 
@@ -64,6 +73,30 @@ def moe_axes(cfg: ModelConfig) -> Params:
     return p
 
 
+def held_experts(cfg: ModelConfig, guard: Optional[GuardSpec],
+                 expert_ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The router's choices over every expert -> (local, held): each
+    pair's index into this chip's bank and whether the bank holds its
+    expert.  Only held ids pass the "expert" fence; a pair whose expert is
+    held elsewhere gets the index one past the bank, set after the fence,
+    so it never wraps onto a held expert."""
+    m = cfg.moe
+    local = expert_ids - m.expert_base
+    held = (local >= 0) & (local < m.held)
+    local = fence(guard, "expert", jnp.where(held, local, 0))
+    return jnp.where(held, local, m.held), held
+
+
+def _route(cfg: ModelConfig, p: Params, xf: jax.Array):
+    """Softmax over every expert, top-k, weights renormalised over the k."""
+    logits = (xf.astype(jnp.float32) @ p["router"])            # (T,E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, expert_ids = jax.lax.top_k(probs, cfg.moe.top_k)
+    gate_vals = gate_vals / jnp.maximum(
+        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    return probs, gate_vals, expert_ids.astype(jnp.int32)
+
+
 def moe_apply(cfg: ModelConfig, p: Params, x: jax.Array,
               guard: Optional[GuardSpec] = None,
               rules: Optional[ShardingRules] = None,
@@ -71,9 +104,10 @@ def moe_apply(cfg: ModelConfig, p: Params, x: jax.Array,
               capacity_factor: float = 2.0) -> Tuple[jax.Array, jax.Array]:
     """x (B,S,d) -> (out (B,S,d), aux_loss scalar).
 
-    Capacity-based dispatch: top-k expert choice per token, tokens routed
-    into per-expert buffers of capacity C = ceil(2*K*T/E); overflow drops
-    (standard).  All shapes static => dry-run friendly.
+    Capacity-based dispatch (training): top-k expert choice per token,
+    tokens routed into per-expert buffers of capacity C = ceil(2*K*T/E);
+    overflow drops (standard), as do pairs with experts held elsewhere.
+    All shapes static => dry-run friendly.
 
     Two dispatch implementations (§Perf hillclimb H1):
 
@@ -89,31 +123,25 @@ def moe_apply(cfg: ModelConfig, p: Params, x: jax.Array,
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
-    E, K = m.num_experts, m.top_k
+    E, K = m.held, m.top_k
     xf = x.reshape(T, d)
 
-    logits = (xf.astype(jnp.float32) @ p["router"])            # (T,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, K)            # (T,K)
+    probs, gate_vals, expert_ids = _route(cfg, p, xf)          # (T,K)
+    # load-balancing aux loss (Switch-style), over every expert
+    me = jnp.mean(probs, axis=0)                                # (E_all,)
+    ce = jnp.mean(jnp.sum(jax.nn.one_hot(
+        expert_ids, m.num_experts, dtype=jnp.float32), axis=1), axis=0)
+    aux = m.num_experts * jnp.sum(me * ce)
     # Guardian: expert ids are data-dependent addresses into the expert
     # bank — fence them into the tenant's expert partition.
-    expert_ids = fence(guard, "expert", expert_ids.astype(jnp.int32))
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-
-    # load-balancing aux loss (Switch-style)
-    me = jnp.mean(probs, axis=0)                                # (E,)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(expert_ids, E, dtype=jnp.float32), axis=1),
-        axis=0)
-    aux = E * jnp.sum(me * ce)
+    expert_ids, held = held_experts(cfg, guard, expert_ids)
 
     capacity = max(int(capacity_factor * K * T / E), 8)
     onehot = jax.nn.one_hot(expert_ids, E, dtype=jnp.float32)   # (T,K,E)
     pos_in_e = (jnp.cumsum(onehot.reshape(T * K, E), axis=0) - 1.0
                 ).reshape(T, K, E)                              # rank
     pos = jnp.sum(pos_in_e * onehot, axis=-1).astype(jnp.int32)  # (T,K)
-    keep = (pos < capacity)
+    keep = (pos < capacity) & held
     gate_vals = gate_vals * keep.astype(gate_vals.dtype)
 
     if dispatch == "einsum":
@@ -174,6 +202,62 @@ def moe_apply(cfg: ModelConfig, p: Params, x: jax.Array,
     return out.reshape(B, S, d), aux
 
 
+def moe_dropless(cfg: ModelConfig, p: Params, x: jax.Array,
+                 guard: Optional[GuardSpec] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """x (B,S,d) -> (out (B,S,d), pairs (B,3) int32), dropping nothing.
+
+    The token–expert pairs are sorted by held expert, those held
+    elsewhere last, and each held expert multiplies exactly its own rows
+    (``jax.lax.ragged_dot``: rows past the held groups come out zero).
+    ``pairs`` counts each batch row's pairs computed here, held elsewhere,
+    and held but dropped (``PAIR_COUNTS``): a held pair is dropped when
+    its sorted row lies outside the rows ``ragged_dot`` gives its expert,
+    as the group sizes place them."""
+    m = cfg.moe
+    B, S, d = x.shape
+    T, K, H = B * S, m.top_k, m.held
+    xf = x.reshape(T, d)
+    _, gate_vals, expert_ids = _route(cfg, p, xf)
+    local, held = held_experts(cfg, guard, expert_ids)        # (T,K)
+    flat = local.reshape(T * K)
+    order = jnp.argsort(flat, stable=True)                    # (T*K,)
+    sizes = jnp.bincount(flat, length=H + 1)[:H].astype(jnp.int32)
+    src = order // K                                          # token
+    xs = jnp.take(xf, src, axis=0)
+
+    def gmm(a, w):
+        return jax.lax.ragged_dot(a, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    if cfg.act == "silu":
+        h = jax.nn.silu(gmm(xs, p["wg"])) * gmm(xs, p["wu"])
+    else:
+        h = jax.nn.gelu(gmm(xs, p["wu"]))
+    y = gmm(h.astype(xf.dtype), p["wd"]).astype(xf.dtype)     # sorted
+    back = jnp.zeros((T * K,), jnp.int32).at[order].set(
+        jnp.arange(T * K, dtype=jnp.int32))
+    y_tk = jnp.take(y, back, axis=0).reshape(T, K, d)
+    w = jnp.where(held, gate_vals, 0.0)
+    out = jnp.einsum("tkd,tk->td", y_tk.astype(jnp.float32), w)
+
+    held_b = jnp.sum(held.reshape(B, S * K), axis=-1, dtype=jnp.int32)
+    # the expert whose rows hold each pair's sorted row (H: past them all)
+    group = jnp.searchsorted(jnp.cumsum(sizes), back, side="right")
+    dropped = held.reshape(T * K) & (group != flat)
+    dropped_b = jnp.sum(dropped.reshape(B, S * K), axis=-1, dtype=jnp.int32)
+    pairs = jnp.stack([held_b, S * K - held_b, dropped_b], -1)
+    return out.astype(x.dtype).reshape(B, S, d), pairs
+
+
+#: the per-row counts of :func:`moe_dropless`, in its column order, under
+#: their telemetry names
+PAIR_COUNTS = ("moe.pairs_held", "moe.pairs_elsewhere", "moe.pairs_dropped")
+#: named scopes of the served steps' operations, by layer kind
+SCOPE_MOE = "guardian.moe"
+SCOPE_ATTN = {SLIDING: "guardian.attn_window", FULL: "guardian.attn_full"}
+
+
 # ---------------------------------------------------------------------------
 # Model (attention blocks reuse the dense transformer pieces)
 # ---------------------------------------------------------------------------
@@ -215,17 +299,45 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     }
 
 
-def _layer(cfg, rules, guard, p, x, positions, aux_acc, dispatch="scatter"):
-    q, k, v = L.qkv_proj(cfg, p["attn"], L.apply_norm(cfg, p["norm1"], x))
-    q, k = L.positions_rope(cfg, q, k, positions)
-    o = L.chunked_attention(q, k, v, causal=True, window=cfg.attn_window, rules=rules)
-    x = x + L.out_proj(cfg, p["attn"], o)
-    h, aux = moe_apply(cfg, p["moe"], L.apply_norm(cfg, p["norm2"], x),
-                       guard, rules, dispatch)
-    x = x + h
-    if rules is not None:
-        x = constrain(x, rules, ("batch", "seq", None))
-    return x, aux_acc + aux
+def over_layers(cfg: ModelConfig, layers: Params, body: Callable, carry,
+                remat: Optional[Callable] = None):
+    """``carry = body(carry, p, lidx, kind)`` for every layer: a scan over
+    the periods of ``cfg.layer_period``, each period unrolled, so that
+    every layer's kind is a static string.  Each layer's weights are
+    indexed from the stacked ``layers`` by its own index, so no period's
+    weights are gathered into a slab first.  ``remat`` wraps the period."""
+    kinds = cfg.layer_period
+
+    def period(carry, first):
+        for j, kind in enumerate(kinds):
+            p = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, first + j, keepdims=False), layers)
+            carry = body(carry, p, first + j, kind)
+        return carry, None
+
+    if remat is not None:
+        period = remat(period)
+    firsts = jnp.arange(0, cfg.n_layers, len(kinds), dtype=jnp.int32)
+    carry, _ = jax.lax.scan(period, carry, firsts)
+    return carry
+
+
+def _qkv(cfg, p, x, positions, kind):
+    h = L.apply_norm(cfg, p["norm1"], x)
+    B, S, _ = h.shape
+    a = p["attn"]
+    # the barrier keeps the heads' layout from reaching back into the
+    # projections: without it the TPU compiler relayouts every layer's wq,
+    # wk and wv in one copy of the stacked weights each step
+    q, k, v = jax.lax.optimization_barrier(
+        (h @ a["wq"], h @ a["wk"], h @ a["wv"]))
+    if cfg.qkv_bias:
+        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
+    q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    q, k = L.positions_rope(cfg, q, k, positions, kind)
+    return q, k, v
 
 
 def forward(cfg: ModelConfig, params: Params, tokens: jax.Array,
@@ -241,20 +353,27 @@ def forward(cfg: ModelConfig, params: Params, tokens: jax.Array,
         positions = jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
 
-    def body(carry, p):
+    def body(carry, p, lidx, kind):
         x, aux = carry
-        x, aux = _layer(cfg, rules, guard, p, x, positions, aux,
-                        dispatch)
-        return (x, aux), None
+        q, k, v = _qkv(cfg, p, x, positions, kind)
+        o = L.chunked_attention(q, k, v, causal=True,
+                                window=cfg.window_of(kind), rules=rules)
+        x = x + L.out_proj(cfg, p["attn"], o)
+        h, a = moe_apply(cfg, p["moe"], L.apply_norm(cfg, p["norm2"], x),
+                         guard, rules, dispatch)
+        x = x + h
+        if rules is not None:
+            x = constrain(x, rules, ("batch", "seq", None))
+        return x, aux + a
 
-    step = body
+    wrap = None
     if remat:
         pol = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                if remat_policy == "dots"
                else jax.checkpoint_policies.nothing_saveable)
-        step = jax.checkpoint(body, policy=pol)
-    (x, aux), _ = jax.lax.scan(step, (x, jnp.float32(0.0)),
-                               params["layers"])
+        wrap = functools.partial(jax.checkpoint, policy=pol)
+    x, aux = over_layers(cfg, params["layers"], body,
+                         (x, jnp.float32(0.0)), wrap)
     x = L.apply_norm(cfg, params["norm_f"], x)
     logits = L.lm_logits(cfg, params["embed"], x)
     return logits, aux / cfg.n_layers
@@ -276,84 +395,94 @@ def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
 
 
 # ---------------------------------------------------------------------------
-# Serving — same cache discipline as the dense model
+# Serving — same cache discipline as the dense model, dropless experts.
+# With ``counts=True`` prefill and decode also return each batch row's
+# ``PAIR_COUNTS`` (B, 3), summed over the layers.
 # ---------------------------------------------------------------------------
+
+def _moe_block(cfg, p, x, guard):
+    with jax.named_scope(SCOPE_MOE):
+        return moe_dropless(cfg, p["moe"], L.apply_norm(cfg, p["norm2"], x),
+                            guard)
+
 
 def prefill(cfg: ModelConfig, params: Params, cache: KV.PagedKVCache,
             tokens: jax.Array, *, guard: Optional[GuardSpec] = None,
             rules: Optional[ShardingRules] = None,
             positions: Optional[jax.Array] = None,
-            dispatch: str = "scatter",
-            ) -> Tuple[KV.PagedKVCache, jax.Array]:
+            counts: bool = False):
     B, S = tokens.shape
     x = L.embed_tokens(params["embed"], tokens, guard)
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
 
-    def body(carry, inp):
-        x, kc, vc = carry
-        p, lidx = inp
-        q, k, v = L.qkv_proj(cfg, p["attn"], L.apply_norm(cfg, p["norm1"], x))
-        q, k = L.positions_rope(cfg, q, k, positions)
-        tmp = dataclasses.replace(cache, k=kc, v=vc)
-        tmp = KV.write_prefill_kv(tmp, lidx, k.astype(kc.dtype),
-                                  v.astype(vc.dtype), guard)
-        o = L.chunked_attention(q, k, v, causal=True,
-                                window=cfg.attn_window, rules=rules)
-        x = x + L.out_proj(cfg, p["attn"], o)
-        h, _ = moe_apply(cfg, p["moe"], L.apply_norm(cfg, p["norm2"], x),
-                         guard, rules, dispatch)
+    def body(carry, p, lidx, kind):
+        x, kc, vc, pairs = carry
+        with jax.named_scope(SCOPE_ATTN[kind]):
+            q, k, v = _qkv(cfg, p, x, positions, kind)
+            tmp = dataclasses.replace(cache, k=kc, v=vc)
+            tmp = KV.write_prefill_kv(tmp, lidx, k.astype(kc.dtype),
+                                      v.astype(vc.dtype), guard)
+            o = L.chunked_attention(q, k, v, causal=True,
+                                    window=cfg.window_of(kind), rules=rules)
+            x = x + L.out_proj(cfg, p["attn"], o)
+        h, n = _moe_block(cfg, p, x, guard)
         x = x + h
         if rules is not None:
             x = constrain(x, rules, ("batch", "seq", None))
-        return (x, tmp.k, tmp.v), None
+        return x, tmp.k, tmp.v, pairs + n
 
-    lidxs = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    (x, kc, vc), _ = jax.lax.scan(body, (x, cache.k, cache.v),
-                                  (params["layers"], lidxs))
+    x, kc, vc, pairs = over_layers(
+        cfg, params["layers"], body,
+        (x, cache.k, cache.v, jnp.zeros((B, len(PAIR_COUNTS)), jnp.int32)))
     cache = dataclasses.replace(cache, k=kc, v=vc,
                                 seq_lens=cache.seq_lens + S)
     x = L.apply_norm(cfg, params["norm_f"], x[:, -1:])
-    return cache, L.lm_logits(cfg, params["embed"], x)[:, 0]
+    logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
+    return (cache, logits, pairs) if counts else (cache, logits)
 
 
 def decode(cfg: ModelConfig, params: Params, cache: KV.PagedKVCache,
            tokens: jax.Array, *, guard: Optional[GuardSpec] = None,
            rules: Optional[ShardingRules] = None,
            positions: Optional[jax.Array] = None,
-           dispatch: str = "scatter"
-           ) -> Tuple[KV.PagedKVCache, jax.Array]:
+           counts: bool = False):
     B = tokens.shape[0]
     x = L.embed_tokens(params["embed"], tokens[:, None], guard)
     if positions is None:
         positions = cache.seq_lens[:, None]
     elif positions.ndim == 1:
         positions = positions[:, None]
+    kv_len = cache.seq_lens + 1
 
-    def body(carry, inp):
-        x, kc, vc = carry
-        p, lidx = inp
-        q, k, v = L.qkv_proj(cfg, p["attn"], L.apply_norm(cfg, p["norm1"], x))
-        q, k = L.positions_rope(cfg, q, k, positions)
-        tmp = dataclasses.replace(cache, k=kc, v=vc)
-        tmp = KV.append_token_kv(tmp, lidx, k.astype(kc.dtype),
-                                 v.astype(vc.dtype), guard)
-        k_hist, v_hist = KV.gather_layer_kv(tmp, lidx, guard, rules)
-        o = L.decode_attention(q, k_hist.astype(q.dtype),
-                               v_hist.astype(q.dtype),
-                               cache.seq_lens + 1,
-                               window=cfg.attn_window)
-        x = x + L.out_proj(cfg, p["attn"], o)
-        h, _ = moe_apply(cfg, p["moe"], L.apply_norm(cfg, p["norm2"], x),
-                         guard, rules, dispatch)
-        x = x + h
-        return (x, tmp.k, tmp.v), None
+    def body(carry, p, lidx, kind):
+        x, kc, vc, pairs = carry
+        window = cfg.window_of(kind)
+        with jax.named_scope(SCOPE_ATTN[kind]):
+            q, k, v = _qkv(cfg, p, x, positions, kind)
+            tmp = dataclasses.replace(cache, k=kc, v=vc)
+            tmp = KV.append_token_kv(tmp, lidx, k.astype(kc.dtype),
+                                     v.astype(vc.dtype), guard)
+            if tmp.global_paged:
+                # a sliding layer reads only the pages of its window
+                k_hist, v_hist, start = KV.gather_window_kv(
+                    tmp, lidx, kv_len, window, guard)
+            else:
+                k_hist, v_hist = KV.gather_layer_kv(tmp, lidx, guard, rules)
+                start = None
+            o = L.decode_attention(q, k_hist.astype(q.dtype),
+                                   v_hist.astype(q.dtype), kv_len,
+                                   window=window, kv_start=start)
+            x = x + L.out_proj(cfg, p["attn"], o)
+        h, n = _moe_block(cfg, p, x, guard)
+        return x + h, tmp.k, tmp.v, pairs + n
 
-    lidxs = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    (x, kc, vc), _ = jax.lax.scan(body, (x, cache.k, cache.v),
-                                  (params["layers"], lidxs))
+    x, kc, vc, pairs = over_layers(
+        cfg, params["layers"], body,
+        (x, cache.k, cache.v, jnp.zeros((B, len(PAIR_COUNTS)), jnp.int32)))
     cache = dataclasses.replace(cache, k=kc, v=vc,
                                 seq_lens=cache.seq_lens + 1)
     x = L.apply_norm(cfg, params["norm_f"], x)
-    return cache, L.lm_logits(cfg, params["embed"], x)[:, 0]
+    logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
+    return (cache, logits, pairs) if counts else (cache, logits)

@@ -10,6 +10,9 @@ of a described ``v5e:2x2`` topology, and compiles:
   model's dtype), with a real ``GuardSpec`` carrying the per-row fence
   params and the page map; the decode step also at the benchmark's
   2 x 1024, where it must read the pool by page, never by layer;
+* the stablelm3b cells' steps at 2 x 1024, pinned op for op;
+* Mellum2's served steps at its benchmark's 2 x 8192 (prefill at the
+  traffic's longest prompt, 5376), within 16 GB;
 * ``fenced_paged_attention`` with ``page_map`` and ``interpret=False``.
 
 The topology is described inside a fixture, never at import: only one
@@ -37,6 +40,7 @@ from repro.models import kvcache as KV
 from repro.models.guard import GuardSpec
 
 ARCH = "stablelm-3b"
+MELLUM = "mellum2-12b-a2.5b"
 MAX_BATCH = 8
 MAX_LEN = 256
 PROMPT_LEN = 16
@@ -71,11 +75,11 @@ def _on(sharding, tree):
         tree)
 
 
-def _served(sharding, max_batch, max_len):
+def _served(sharding, max_batch, max_len, arch=ARCH, prompt_len=PROMPT_LEN):
     """The manager, step bundle and operand shapes of the served paged
     path, built as ``ServeEngine(paged=True)`` on ``make_shared_manager``
     builds them (three tenants of 16 pages, beside the scratch extent)."""
-    cfg = get_config(ARCH)
+    cfg = get_config(arch)
     api = get_model(cfg)
     mgr = make_shared_manager(1, max_batch=max_batch, max_len=max_len,
                               paged=True)
@@ -94,6 +98,7 @@ def _served(sharding, max_batch, max_len):
         policy=mgr.policy,
         vocab=FenceParams(base=0, size=1 << (cfg.vocab - 1).bit_length()),
         kv=table.gather(jnp.asarray(cols)),
+        expert=FenceParams(base=0, size=cfg.moe.held) if cfg.moe else None,
         page=FenceParams(base=0, size=n_pages),
         page_map=jnp.asarray(pages.page_map))
 
@@ -101,13 +106,14 @@ def _served(sharding, max_batch, max_len):
         cfg, max_batch, max_len, n_pages))
     pool, meta = split_cache_pool(cache)
     steps = build_trusted_serve_steps(
-        api, f"chip-compile:{ARCH}:{max_batch}x{max_len}")
+        api, f"chip-compile:{arch}:{max_batch}x{max_len}",
+        counts=bool(api.counters))
     steps.register(mgr, pool)
     params = jax.eval_shape(api.init, jax.random.PRNGKey(0))
     ops = dict(
         arena=mgr.arena.buf, pool=pool, params=params, meta=meta,
         guard=guard,
-        prefill_x={"tokens": jax.ShapeDtypeStruct((max_batch, PROMPT_LEN),
+        prefill_x={"tokens": jax.ShapeDtypeStruct((max_batch, prompt_len),
                                                   jnp.int32)},
         decode_x=jax.ShapeDtypeStruct((max_batch,), jnp.int32))
     return mgr, steps, {k: _on(sharding, v) for k, v in ops.items()}
@@ -165,6 +171,71 @@ def test_served_decode_reads_pages_not_layers(served_bench):
     # 7,739,044,352 B on a v5e; with the float32 pool it was 9,886,528,000
     arg_bytes = compiled.memory_analysis().argument_size_in_bytes
     assert arg_bytes < 7.75e9
+
+
+#: the stablelm3b cells' served steps at 2 x 1024 as compiled before the
+#: moe family learned layer kinds, held experts and dropless serving:
+#: argument and temporary bytes, and the count of copy and gather ops and
+#: of all ops.  Those changes leave the dense path's programs alone, op
+#: for op.
+DENSE_PINS = {
+    ("prefill", 768): (7739049984, 151833600, 8, 2, 931),
+    ("prefill", 1008): (7739052032, 275563008, 8, 2, 939),
+    ("decode", 768): (7739044352, 0, 9, 6, 1120),
+}
+
+
+@pytest.mark.parametrize("phase,prompt_len", sorted(DENSE_PINS))
+def test_stablelm_served_steps_are_pinned(one_chip, phase, prompt_len):
+    """The stablelm3b cells' prefill (the chat mix's 768 and the
+    interactive mix's 1008) and decode steps compile to the pinned
+    programs."""
+    served = _served(one_chip, 2, 1024, prompt_len=prompt_len)
+    compiled = _compile(served, phase)
+    mem = compiled.memory_analysis()
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = .*? ([a-z][a-z0-9-]*)\(",
+                     compiled.as_text(), flags=re.M)
+    assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+            ops.count("copy"), ops.count("gather"), len(ops)) == \
+        DENSE_PINS[phase, prompt_len]
+
+
+def _copies(text, array):
+    """``copy`` instructions whose output holds as many bfloat16 elements
+    as ``array``: a copy of it in its own shape or any view of it (the
+    pool's (L * P, page, KH, D) page rows, say)."""
+    n = 0
+    for dims in re.findall(r"= bf16\[([0-9,]+)\]\S* copy\(", text):
+        n += int(np.prod([int(d) for d in dims.split(",")])) == array.size
+    return n
+
+
+@pytest.fixture(scope="module")
+def served_mellum(one_chip):
+    """The benchmark's Mellum2 shape: 2 rows of 8192, 512 pool pages, the
+    longest prompt of its traffic (5376) for prefill."""
+    return _served(one_chip, 2, 8192, arch=MELLUM, prompt_len=5376)
+
+
+@pytest.mark.parametrize("phase,copies", [("prefill", 4), ("decode", 0)])
+def test_mellum_steps_compile_and_fit(served_mellum, phase, copies):
+    """Mellum2's served steps at the benchmark's 2 x 8192 fit one v5e
+    beside the 7.66 GB of weights and the 1.88 GB pool.  The decode step
+    reads the pool by page and copies none of it, in no view; the 2 x
+    5376 prefill step relayouts each pool half before its layer loop and
+    back after it, four copies (the whole-pool copy fault, at prefill),
+    and still fits.  Neither step relayouts the stacked q projection."""
+    ops = served_mellum[2]
+    pool = ops["pool"]["k"]
+    assert pool.shape == (28, 512, KV.PAGE_SIZE, 4, 128)
+    assert pool.dtype == jnp.bfloat16
+    compiled = _compile(served_mellum, phase)
+    mem = compiled.memory_analysis()
+    assert 9.5e9 < mem.argument_size_in_bytes < 9.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    text = compiled.as_text()
+    assert _copies(text, pool) == copies
+    assert _copies(text, ops["params"]["layers"]["attn"]["wq"]) == 0
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
