@@ -117,7 +117,7 @@ def _moe_api(cfg: ModelConfig) -> ModelAPI:
             cfg, params, cache, batch["tokens"], **kw),
         decode=lambda params, cache, tokens, **kw: MOE.decode(
             cfg, params, cache, tokens, **kw),
-        counters=MOE.PAIR_COUNTS,
+        counters=MOE.ROW_COUNTS,
     )
 
 

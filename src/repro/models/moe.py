@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm
 
 from repro.configs.base import FULL, SLIDING, ModelConfig
 from repro.distributed.sharding import ShardingRules, constrain
@@ -202,18 +203,98 @@ def moe_apply(cfg: ModelConfig, p: Params, x: jax.Array,
     return out.reshape(B, S, d), aux
 
 
-def moe_dropless(cfg: ModelConfig, p: Params, x: jax.Array,
-                 guard: Optional[GuardSpec] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """x (B,S,d) -> (out (B,S,d), pairs (B,3) int32), dropping nothing.
+#: the scoped VMEM a grouped-matmul tile set may take, double-buffered
+#: (the TPU's default scoped limit is 16 MiB)
+GMM_VMEM_BYTES = 14 << 20
 
-    The token–expert pairs are sorted by held expert, those held
-    elsewhere last, and each held expert multiplies exactly its own rows
-    (``jax.lax.ragged_dot``: rows past the held groups come out zero).
-    ``pairs`` counts each batch row's pairs computed here, held elsewhere,
-    and held but dropped (``PAIR_COUNTS``): a held pair is dropped when
-    its sorted row lies outside the rows ``ragged_dot`` gives its expert,
-    as the group sizes place them."""
+
+def _tiles(dim: int):
+    """Tile sizes of one dimension, largest first: the whole dimension,
+    then its divisors that are multiples of 128 lanes."""
+    return [dim] + [t for t in range(dim - dim % 128, 0, -128)
+                    if t < dim and dim % t == 0]
+
+
+def gmm_tiling(m: int, k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
+    """(tm, tk, tn) of ``megablox.gmm`` for ``m`` sorted rows of depth
+    ``k`` and width ``n``: the rows' tile follows the row count (a decode
+    step's 2 x top-8 = 16 rows take one tile of 16, a prefill wave's take
+    512s), then the widest, then deepest, weight tile whose buffers fit
+    ``GMM_VMEM_BYTES`` (wide first: each output tile reads its rows once);
+    the rows' tile halves while none fits."""
+    def fitting(tm):
+        return [(tm, tk, tn) for tn in _tiles(n) for tk in _tiles(k)
+                if 2 * (tm * tk + tk * tn) * itemsize + 3 * tm * tn * 4
+                <= GMM_VMEM_BYTES]
+
+    tm = min(512, max(16, 1 << (m - 1).bit_length()))
+    while tm > 16 and not fitting(tm):
+        tm //= 2
+    return (fitting(tm) or [(tm, _tiles(k)[-1], _tiles(n)[-1])])[0]
+
+
+def grouped_megablox(a: jax.Array, w: jax.Array, sizes: jax.Array, *,
+                     interpret: bool) -> jax.Array:
+    """a (m, k) rows sorted by group @ w (G, k, n), group g's rows by w[g]:
+    the TPU's grouped matmul (Pallas), float32 out.  Each visited group's
+    weight tiles are read where ``w`` holds them, and a group with no rows
+    is never visited; rows past the groups are left unwritten."""
+    m, k = a.shape
+    tm, tk, tn = gmm_tiling(m, k, w.shape[2], w.dtype.itemsize)
+    pad = -m % tm
+    if pad:
+        a = jnp.pad(a, ((0, pad), (0, 0)))
+    out = megablox_gmm(a, w, sizes, preferred_element_type=jnp.float32,
+                       tiling=(tm, tk, tn), interpret=interpret)
+    return out[:m] if pad else out
+
+
+def grouped_ragged(a: jax.Array, w: jax.Array,
+                   sizes: jax.Array) -> jax.Array:
+    """The same products by ``jax.lax.ragged_dot``, float32 out; rows past
+    the groups come out zero."""
+    return jax.lax.ragged_dot(a, w, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _expert_mlp(cfg: ModelConfig, p: Params, grouped: Callable,
+                xs: jax.Array, sizes: jax.Array) -> jax.Array:
+    """The experts' MLP over rows sorted by expert, in float32."""
+    if cfg.act == "silu":
+        h = jax.nn.silu(grouped(xs, p["wg"], sizes)) * \
+            grouped(xs, p["wu"], sizes)
+    else:
+        h = jax.nn.gelu(grouped(xs, p["wu"], sizes))
+    return grouped(h.astype(xs.dtype), p["wd"], sizes)
+
+
+def expert_bank(moe: Params) -> Params:
+    """The stacked layers' held experts as one bank of ``n_layers * held``
+    experts, layer ``l``'s from ``l * held`` on: merging the leading
+    dimensions is a view and copies nothing."""
+    return {k: a.reshape(-1, *a.shape[2:]) for k, a in moe.items()
+            if k != "router"}
+
+
+def moe_dropless(cfg: ModelConfig, p: Params, x: jax.Array,
+                 guard: Optional[GuardSpec] = None, lidx=0
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """x (B,S,d) -> (out (B,S,d), counts (B,4) int32), dropping nothing.
+
+    ``p`` holds layer ``lidx``'s router and a bank of experts (``wg``,
+    ``wu``, ``wd`` of ``n * held``; :func:`expert_bank`), the layer's own
+    from ``lidx * held`` on: one layer's experts alone with ``lidx`` 0.
+    The token-expert pairs are sorted by held expert, those held elsewhere
+    last, and their group sizes placed at the layer's experts, every other
+    group empty, so the products read the bank where it lies and no pair
+    reaches another layer's experts.  On a TPU they run as the grouped
+    matmul of ``megablox`` (a group without rows is never fetched),
+    elsewhere as ``ragged_dot``; either way the rows of pairs held
+    elsewhere are selected away, never weighted by zero, since the kernel
+    leaves them unwritten.  ``counts`` holds each batch row's
+    ``ROW_COUNTS``: pairs computed here, held elsewhere, held but dropped
+    (a held pair whose sorted row lies outside the rows the placed group
+    sizes give its expert), and held experts reached."""
     m = cfg.moe
     B, S, d = x.shape
     T, K, H = B * S, m.top_k, m.held
@@ -223,36 +304,37 @@ def moe_dropless(cfg: ModelConfig, p: Params, x: jax.Array,
     flat = local.reshape(T * K)
     order = jnp.argsort(flat, stable=True)                    # (T*K,)
     sizes = jnp.bincount(flat, length=H + 1)[:H].astype(jnp.int32)
-    src = order // K                                          # token
-    xs = jnp.take(xf, src, axis=0)
-
-    def gmm(a, w):
-        return jax.lax.ragged_dot(a, w, sizes,
-                                  preferred_element_type=jnp.float32)
-
-    if cfg.act == "silu":
-        h = jax.nn.silu(gmm(xs, p["wg"])) * gmm(xs, p["wu"])
-    else:
-        h = jax.nn.gelu(gmm(xs, p["wu"]))
-    y = gmm(h.astype(xf.dtype), p["wd"]).astype(xf.dtype)     # sorted
+    placed = jax.lax.dynamic_update_slice(
+        jnp.zeros((p["wu"].shape[0],), jnp.int32), sizes, (lidx * H,))
+    xs = jnp.take(xf, order // K, axis=0)
+    y = jax.lax.platform_dependent(
+        xs, placed,
+        tpu=functools.partial(_expert_mlp, cfg, p, functools.partial(
+            grouped_megablox, interpret=False)),
+        default=functools.partial(_expert_mlp, cfg, p, grouped_ragged))
+    y = y.astype(xf.dtype)                                    # sorted
     back = jnp.zeros((T * K,), jnp.int32).at[order].set(
         jnp.arange(T * K, dtype=jnp.int32))
     y_tk = jnp.take(y, back, axis=0).reshape(T, K, d)
-    w = jnp.where(held, gate_vals, 0.0)
-    out = jnp.einsum("tkd,tk->td", y_tk.astype(jnp.float32), w)
+    y_tk = jnp.where(held[..., None], y_tk.astype(jnp.float32), 0.0)
+    out = jnp.einsum("tkd,tk->td", y_tk, gate_vals)
 
     held_b = jnp.sum(held.reshape(B, S * K), axis=-1, dtype=jnp.int32)
-    # the expert whose rows hold each pair's sorted row (H: past them all)
-    group = jnp.searchsorted(jnp.cumsum(sizes), back, side="right")
-    dropped = held.reshape(T * K) & (group != flat)
+    # the bank's expert whose rows hold each pair's sorted row
+    group = jnp.searchsorted(jnp.cumsum(placed), back, side="right")
+    dropped = held.reshape(T * K) & (group != lidx * H + flat)
     dropped_b = jnp.sum(dropped.reshape(B, S * K), axis=-1, dtype=jnp.int32)
-    pairs = jnp.stack([held_b, S * K - held_b, dropped_b], -1)
-    return out.astype(x.dtype).reshape(B, S, d), pairs
+    reached = jnp.zeros((B, H + 1), bool).at[
+        jnp.arange(T * K) // (S * K), flat].set(True)
+    read_b = jnp.sum(reached[:, :H], axis=-1, dtype=jnp.int32)
+    counts = jnp.stack([held_b, S * K - held_b, dropped_b, read_b], -1)
+    return out.astype(x.dtype).reshape(B, S, d), counts
 
 
 #: the per-row counts of :func:`moe_dropless`, in its column order, under
 #: their telemetry names
-PAIR_COUNTS = ("moe.pairs_held", "moe.pairs_elsewhere", "moe.pairs_dropped")
+ROW_COUNTS = ("moe.pairs_held", "moe.pairs_elsewhere", "moe.pairs_dropped",
+              "moe.experts_read")
 #: named scopes of the served steps' operations, by layer kind
 SCOPE_MOE = "guardian.moe"
 SCOPE_ATTN = {SLIDING: "guardian.attn_window", FULL: "guardian.attn_full"}
@@ -397,13 +479,22 @@ def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
 # ---------------------------------------------------------------------------
 # Serving — same cache discipline as the dense model, dropless experts.
 # With ``counts=True`` prefill and decode also return each batch row's
-# ``PAIR_COUNTS`` (B, 3), summed over the layers.
+# ``ROW_COUNTS`` (B, 4), summed over the layers.
 # ---------------------------------------------------------------------------
 
-def _moe_block(cfg, p, x, guard):
+def _served_layers(params: Params) -> Tuple[Params, Params]:
+    """(the stacked layers the period scan indexes by layer, the expert
+    bank it leaves whole): a layer's experts are read from the bank where
+    they lie, never sliced out of the stacks first."""
+    layers = params["layers"]
+    scanned = dict(layers, moe={"router": layers["moe"]["router"]})
+    return scanned, expert_bank(layers["moe"])
+
+
+def _moe_block(cfg, p, bank, lidx, x, guard):
     with jax.named_scope(SCOPE_MOE):
-        return moe_dropless(cfg, p["moe"], L.apply_norm(cfg, p["norm2"], x),
-                            guard)
+        return moe_dropless(cfg, dict(bank, router=p["moe"]["router"]),
+                            L.apply_norm(cfg, p["norm2"], x), guard, lidx)
 
 
 def prefill(cfg: ModelConfig, params: Params, cache: KV.PagedKVCache,
@@ -427,15 +518,16 @@ def prefill(cfg: ModelConfig, params: Params, cache: KV.PagedKVCache,
             o = L.chunked_attention(q, k, v, causal=True,
                                     window=cfg.window_of(kind), rules=rules)
             x = x + L.out_proj(cfg, p["attn"], o)
-        h, n = _moe_block(cfg, p, x, guard)
+        h, n = _moe_block(cfg, p, bank, lidx, x, guard)
         x = x + h
         if rules is not None:
             x = constrain(x, rules, ("batch", "seq", None))
         return x, tmp.k, tmp.v, pairs + n
 
+    layers, bank = _served_layers(params)
     x, kc, vc, pairs = over_layers(
-        cfg, params["layers"], body,
-        (x, cache.k, cache.v, jnp.zeros((B, len(PAIR_COUNTS)), jnp.int32)))
+        cfg, layers, body,
+        (x, cache.k, cache.v, jnp.zeros((B, len(ROW_COUNTS)), jnp.int32)))
     cache = dataclasses.replace(cache, k=kc, v=vc,
                                 seq_lens=cache.seq_lens + S)
     x = L.apply_norm(cfg, params["norm_f"], x[:, -1:])
@@ -475,12 +567,13 @@ def decode(cfg: ModelConfig, params: Params, cache: KV.PagedKVCache,
                                    v_hist.astype(q.dtype), kv_len,
                                    window=window, kv_start=start)
             x = x + L.out_proj(cfg, p["attn"], o)
-        h, n = _moe_block(cfg, p, x, guard)
+        h, n = _moe_block(cfg, p, bank, lidx, x, guard)
         return x + h, tmp.k, tmp.v, pairs + n
 
+    layers, bank = _served_layers(params)
     x, kc, vc, pairs = over_layers(
-        cfg, params["layers"], body,
-        (x, cache.k, cache.v, jnp.zeros((B, len(PAIR_COUNTS)), jnp.int32)))
+        cfg, layers, body,
+        (x, cache.k, cache.v, jnp.zeros((B, len(ROW_COUNTS)), jnp.int32)))
     cache = dataclasses.replace(cache, k=kc, v=vc,
                                 seq_lens=cache.seq_lens + 1)
     x = L.apply_norm(cfg, params["norm_f"], x)

@@ -12,7 +12,8 @@ of a described ``v5e:2x2`` topology, and compiles:
   2 x 1024, where it must read the pool by page, never by layer;
 * the stablelm3b cells' steps at 2 x 1024, pinned op for op;
 * Mellum2's served steps at its benchmark's 2 x 8192 (prefill at the
-  traffic's longest prompt, 5376), within 16 GB;
+  traffic's longest prompt, 5376), within 16 GB, their expert products
+  the grouped matmul over the whole stacked bank;
 * ``fenced_paged_attention`` with ``page_map`` and ``interpret=False``.
 
 The topology is described inside a fixture, never at import: only one
@@ -217,6 +218,20 @@ def served_mellum(one_chip):
     return _served(one_chip, 2, 8192, arch=MELLUM, prompt_len=5376)
 
 
+def _expert_slices(text, bank):
+    """Instructions whose output holds bfloat16 expert weights in the
+    bank's trailing (depth, width) shape but fewer experts than the
+    whole bank: a layer's or a period's experts taken out of the stacks."""
+    n = 0
+    for out in re.findall(r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ", text,
+                          flags=re.M):
+        for dims in re.findall(r"bf16\[([0-9,]+)\]", out):
+            dims = [int(d) for d in dims.split(",")]
+            n += (dims[-2:] == list(bank.shape[-2:])
+                  and int(np.prod(dims[:-2])) < np.prod(bank.shape[:-2]))
+    return n
+
+
 @pytest.mark.parametrize("phase,copies", [("prefill", 4), ("decode", 0)])
 def test_mellum_steps_compile_and_fit(served_mellum, phase, copies):
     """Mellum2's served steps at the benchmark's 2 x 8192 fit one v5e
@@ -224,7 +239,9 @@ def test_mellum_steps_compile_and_fit(served_mellum, phase, copies):
     reads the pool by page and copies none of it, in no view; the 2 x
     5376 prefill step relayouts each pool half before its layer loop and
     back after it, four copies (the whole-pool copy fault, at prefill),
-    and still fits.  Neither step relayouts the stacked q projection."""
+    and still fits.  Neither step relayouts the stacked q projection, and
+    neither takes a layer's or a period's experts out of the stacked
+    bank: the grouped matmul reads them where they lie."""
     ops = served_mellum[2]
     pool = ops["pool"]["k"]
     assert pool.shape == (28, 512, KV.PAGE_SIZE, 4, 128)
@@ -236,6 +253,10 @@ def test_mellum_steps_compile_and_fit(served_mellum, phase, copies):
     text = compiled.as_text()
     assert _copies(text, pool) == copies
     assert _copies(text, ops["params"]["layers"]["attn"]["wq"]) == 0
+    moe = ops["params"]["layers"]["moe"]
+    for name in ("wg", "wu", "wd"):
+        assert _expert_slices(text, moe[name]) == 0, name
+    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

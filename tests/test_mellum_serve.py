@@ -12,6 +12,7 @@ times below a bfloat16 rounding of the same logits (about 1e-2).
 """
 
 import dataclasses
+import functools
 import json
 
 import jax
@@ -384,3 +385,269 @@ def test_steps_count_pairs_only_with_telemetry(tiny):
     assert out[0] == out[1]
     assert names[0].endswith(":counts]")
     assert not names[1].endswith(":counts]")
+
+
+# ---------------------------------------------------------------------------
+# Expert products over the whole bank (no per-layer slice of the stacks)
+# ---------------------------------------------------------------------------
+
+#: the served products' two paths: ``ragged_dot`` (every platform but the
+#: TPU) and the TPU's grouped matmul, interpreted here
+PRODUCT_PATHS = {"ragged_dot": MOE.grouped_ragged,
+                 "megablox": functools.partial(MOE.grouped_megablox,
+                                               interpret=True)}
+
+
+def _sliced_products(pc, p, h):
+    """The layer's expert products as served before the bank: its experts
+    sliced out of the stacks, one ``ragged_dot`` per product over them
+    alone, the pairs held elsewhere weighted by zero."""
+    m = pc.moe
+    B, S, d = h.shape
+    T, K, H = B * S, m.top_k, m.held
+    xf = h.reshape(T, d)
+    _, gate, ids = MOE._route(pc, p, xf)
+    local, held = MOE.held_experts(pc, None, ids)
+    flat = local.reshape(T * K)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=H + 1)[:H].astype(jnp.int32)
+    xs = jnp.take(xf, order // K, axis=0)
+
+    def mm(a, w):
+        return jax.lax.ragged_dot(a, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    y = mm((jax.nn.silu(mm(xs, p["wg"])) * mm(xs, p["wu"])).astype(
+        xf.dtype), p["wd"]).astype(xf.dtype)
+    back = jnp.zeros((T * K,), jnp.int32).at[order].set(
+        jnp.arange(T * K, dtype=jnp.int32))
+    y_tk = jnp.take(y, back, axis=0).reshape(T, K, d)
+    out = jnp.einsum("tkd,tk->td", y_tk.astype(jnp.float32),
+                     jnp.where(held, gate, 0.0))
+    return np.asarray(out.reshape(B, S, d)), np.asarray(held)
+
+
+#: router biases: the seeded router as drawn, every token's first choice
+#: on held expert 3, every pair on an expert held elsewhere (8-15), every
+#: pair on a held one (0-7)
+ROUTINGS = {"seeded": None, "one_expert": (3, 4), "all_elsewhere": (8, 16),
+            "all_held": (0, 8)}
+
+
+def _bank_layer(pc, params, layer, routing):
+    """Layer ``layer``'s router (biased by ``routing``) over the tiny
+    model's whole bank, and the same layer's experts sliced out."""
+    moe = params["layers"]["moe"]
+    router = moe["router"][layer]
+    if ROUTINGS[routing] is not None:
+        lo, hi = ROUTINGS[routing]
+        router = router.at[:, lo:hi].add(50.0)
+    bank = dict(MOE.expert_bank(moe), router=router)
+    sliced = {k: moe[k][layer] for k in ("wg", "wu", "wd")}
+    return bank, dict(sliced, router=router)
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+@pytest.mark.parametrize("path", sorted(PRODUCT_PATHS))
+@pytest.mark.parametrize("layer", [0, 2, 3])
+def test_bank_products_equal_the_sliced_layers(tiny, monkeypatch, layer,
+                                               path, routing):
+    """The products over the whole bank, groups placed at the layer's
+    experts (first, middle and last layer), equal the products over the
+    layer's experts sliced out of the stacks, on both paths, under a
+    seeded router, one that sends every token to one expert, one whose
+    pairs are all held elsewhere (every group empty) and one whose pairs
+    are all held."""
+    m, block, pc, w, params = tiny
+    monkeypatch.setattr(MOE, "grouped_ragged", PRODUCT_PATHS[path])
+    bank, sliced = _bank_layer(pc, params, layer, routing)
+    h = jnp.abs(_layer_input(m, 20 + layer))
+    want, held = _sliced_products(pc, sliced, h)
+    out, counts = MOE.moe_dropless(pc, bank, h, lidx=layer)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=0, atol=ATOL)
+    counts = np.asarray(counts)
+    n_held = int(held.sum())
+    assert counts[:, 0].sum() == n_held
+    assert counts[:, 2].sum() == 0
+    if routing == "all_elsewhere":
+        assert n_held == 0 and np.abs(np.asarray(out)).max() == 0.0
+    if routing == "all_held":
+        assert counts[:, 1].sum() == 0
+
+
+def test_rows_the_kernel_leaves_unwritten_never_reach_the_output(
+        tiny, monkeypatch):
+    """The grouped matmul leaves the rows of pairs held elsewhere
+    unwritten.  With those rows poisoned to NaN (the kernel's
+    ``existing_out``), the layer's output stays finite and equal to the
+    ``ragged_dot`` path's: the rows are selected away, not weighted by
+    zero."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    m, block, pc, w, params = tiny
+    bank, _ = _bank_layer(pc, params, 2, "seeded")
+    # 2 x 32 tokens x top-4: 256 sorted rows, whole tiles of rows
+    h = jax.random.normal(jax.random.PRNGKey(21), (2, 32, m.d_model))
+    want, counts = MOE.moe_dropless(pc, bank, h, lidx=2)
+    assert 0 < int(counts[:, 0].sum()) and 0 < int(counts[:, 1].sum())
+    poisoned = []
+
+    def poison(a, wt, sizes):
+        rows, k = a.shape
+        tiling = MOE.gmm_tiling(rows, k, wt.shape[2], wt.dtype.itemsize)
+        assert rows % tiling[0] == 0
+        out = gmm(a, wt, sizes, preferred_element_type=jnp.float32,
+                  tiling=tiling, interpret=True,
+                  existing_out=jnp.full((rows, wt.shape[2]), jnp.nan,
+                                        jnp.float32))
+        poisoned.append(int(np.isnan(np.asarray(out)).any(-1).sum()))
+        return out
+
+    monkeypatch.setattr(MOE, "grouped_ragged", poison)
+    got, _ = MOE.moe_dropless(pc, bank, h, lidx=2)
+    # every product left the rows of the pairs held elsewhere as NaN
+    assert poisoned == [int(counts[:, 1].sum())] * 3
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=ATOL)
+
+
+def _bank_leaves(args):
+    """Indices, among the flattened ``args``, of the stacked experts'
+    ``wg``, ``wu`` and ``wd``."""
+    leaves = jax.tree_util.tree_flatten_with_path(args)[0]
+    return [i for i, (path, _) in enumerate(leaves)
+            if "'moe'" in jax.tree_util.keystr(path)
+            and jax.tree_util.keystr(path).endswith(("['wg']", "['wu']",
+                                                     "['wd']"))]
+
+
+#: primitives that take a part of an array out of it
+SLICING = {"dynamic_slice", "gather", "slice"}
+#: the expert products: what reaches them may be the bank itself
+PRODUCTS = {"ragged_dot_general", "pallas_call"}
+
+
+def _bank_reads(jaxpr, tainted, found):
+    """Walk ``jaxpr`` from the variables in ``tainted`` (the bank, then
+    what is computed from it): record in ``found``, by equation, each
+    slicing op and each expert product that takes from them, and return
+    which of the outputs are computed from them."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
+
+    tainted = set(tainted)
+
+    def inner(j, hit):
+        j = j.jaxpr if isinstance(j, ClosedJaxpr) else j
+        return _bank_reads(j, [v for v, t in zip(j.invars, hit) if t],
+                           found)
+
+    for eqn in jaxpr.eqns:
+        hit = [not isinstance(v, Literal) and v in tainted
+               for v in eqn.invars]
+        if not any(hit):
+            continue
+        name, prm = eqn.primitive.name, eqn.params
+        if name in PRODUCTS or name in SLICING:
+            found[id(eqn)] = name
+            continue
+        outs = [True] * len(eqn.outvars)
+        if name == "cond":
+            outs = [any(o) for o in zip(*(inner(b, hit[1:])
+                                           for b in prm["branches"]))]
+        elif name in ("scan", "while"):
+            if name == "scan":
+                body, n0, n = prm["jaxpr"], prm["num_consts"], \
+                    prm["num_carry"]
+                if any(hit[n0 + n:]):
+                    found[id(eqn)] = "scan over the bank"
+            else:
+                body, n0 = prm["body_jaxpr"], prm["body_nconsts"]
+                hit, n = hit[prm["cond_nconsts"]:], len(eqn.outvars)
+            while True:         # until the carry's taint settles
+                outs = inner(body, hit)
+                carry = [a or b for a, b in zip(hit[n0:n0 + n], outs[:n])]
+                if carry == hit[n0:n0 + n]:
+                    break
+                hit = hit[:n0] + carry + hit[n0 + n:]
+        else:
+            subs = [v for v in prm.values()
+                    if isinstance(v, (Jaxpr, ClosedJaxpr))]
+            if subs:
+                outs = inner(subs[0], hit)
+        tainted.update(v for v, t in zip(eqn.outvars, outs) if t)
+    return [not isinstance(v, Literal) and v in tainted
+            for v in jaxpr.outvars]
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_served_steps_take_no_expert_slice_out_of_the_bank(tiny, phase):
+    """Mellum2's served steps hand the expert products the stacked bank
+    itself: no dynamic slice or gather takes a layer's ``wg``, ``wu`` or
+    ``wd`` out of the stacks before the products, on either path."""
+    m, block, pc, w, params = tiny
+    api = get_model(pc)
+    cache = KV.init_global_kv_cache(pc, 2, MAX_LEN, total_pages=16)
+    if phase == "prefill":
+        args = (params, cache, {"tokens": jnp.ones((2, 24), jnp.int32)})
+        step = api.prefill
+    else:
+        args = (params, cache, jnp.ones((2,), jnp.int32))
+        step = api.decode
+    closed = jax.make_jaxpr(lambda a: step(*a, counts=True))(args)
+    bank = [closed.jaxpr.invars[i] for i in _bank_leaves(args)]
+    assert len(bank) == 3
+    for weights in bank:
+        found = {}
+        _bank_reads(closed.jaxpr, [weights], found)
+        # each of wg, wu, wd reaches both paths' products, and only them
+        assert sorted(set(found.values())) == ["pallas_call",
+                                               "ragged_dot_general"]
+
+
+def test_experts_read_counts_each_rows_reached_held_experts(tiny):
+    """``moe.experts_read``: for each batch row, the held experts that at
+    least one of its token-expert pairs reaches, summed over the layers,
+    as a seeded router's top-k choices give them."""
+    m, block, pc, w, params = tiny
+    moe = params["layers"]["moe"]
+    bank = MOE.expert_bank(moe)
+    K, H = pc.moe.top_k, pc.moe.held
+    x = _layer_input(m, 22)
+    want = np.zeros(x.shape[0], np.int64)
+    got = np.zeros(x.shape[0], np.int64)
+    for layer in range(pc.n_layers):
+        router = np.asarray(moe["router"][layer], np.float64)
+        logits = np.asarray(x, np.float64) @ router          # (B, S, E)
+        top = np.argsort(-logits, axis=-1)[..., :K]
+        for b in range(x.shape[0]):
+            want[b] += len({e for e in top[b].ravel() if e < H})
+        _, counts = MOE.moe_dropless(pc, dict(bank, router=moe["router"][
+            layer]), x, lidx=layer)
+        got += np.asarray(counts)[:, 3]
+    assert (want > 0).all()
+    np.testing.assert_array_equal(got, want)
+
+
+def test_experts_read_is_counted_only_with_telemetry(tiny):
+    """Served with telemetry on, a tenant's ``moe.experts_read`` is
+    positive and at most every held expert of every layer a step; with
+    telemetry off the steps count nothing."""
+    m, block, pc, w, params = tiny
+    prompt = np.random.default_rng(23).integers(1, m.vocab, 40) \
+        .astype(np.int32)
+    new = 5
+    for telemetry in (True, False):
+        eng = ServeEngine(pc, max_batch=2, max_len=MAX_LEN, paged=True,
+                          seed=0, telemetry=telemetry)
+        eng.params = params
+        eng.register_tenant("t0", MAX_LEN // KV.PAGE_SIZE)
+        eng.submit("t0", prompt, max_new=new)
+        serve_continuous([eng], max_new_tokens=new)
+        counters = eng.manager.telemetry.registry.snapshot()["counters"]
+        if not telemetry:
+            assert "moe.experts_read" not in counters
+            continue
+        read = counters["moe.experts_read"]["t0"]
+        steps = new          # one prefill, then new - 1 decode steps
+        assert 0 < read <= pc.n_layers * pc.moe.held * steps
