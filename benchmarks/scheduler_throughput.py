@@ -21,22 +21,16 @@ Three serving-plane suites ride along:
   commit path (``fenced``), vs the blind-trust reference (``trusted``).
   The rows are ``gate=skip`` (informational, like the elastic suite) but
   the elision speedup self-asserts >= 1.0.
-* ``sched.jit.*`` — the trusted-step path compiled (``jit_trusted``,
-  the default) vs the eager fallback: one device program per step vs one
-  dispatch per op inside the step.
+* ``sched.jit.compiled`` — the trusted-step path: one compiled device
+  program per step.
 * ``sched.multiengine.*`` — N ServeEngines sharing one GuardianManager,
   their lockstep prefill/decode steps fused into one compiled device
-  step per drain, vs N independent engines.  Three configurations per
-  engine count so the win decomposes: ``.eager.Ne`` = N independent
-  engines on the eager per-launch plane (each its own manager — the
-  pre-compilation serving path), ``.independent.Ne`` = the same but with
-  compiled trusted steps (jit only, no sharing), ``.fused.Ne`` = shared
-  manager + fused device steps (the full hot path).  The fused drain
-  must beat N independent engines by >= 1.5x at 4 engines (acceptance
-  bar, measured against the eager plane; the fused row also reports
-  ``vs_jit`` — the residual fusion-only margin over already-compiled
-  independent engines, which on this CPU host is bounded by dispatch
-  amortization).
+  step per drain, vs N independent engines.  Two configurations per
+  engine count: ``.independent.Ne`` = N engines, each its own manager,
+  ``.fused.Ne`` = shared manager + fused device steps (the full hot
+  path); the fused row's ``speedup`` is the fusion margin over the
+  independent engines, which on a CPU host is bounded by dispatch
+  amortization.
 * ``telemetry.overhead`` — the flight recorder's tax on the fused
   BITWISE drain (registry+trace on vs off); self-asserts <= 5%.
 
@@ -339,12 +333,12 @@ def _bench_telemetry_overhead(out: List[str]) -> None:
 
 
 # --------------------------------------------------------------------- #
-# Trusted-step jit: compiled vs eager framework steps
+# Trusted-step jit: compiled framework steps
 # --------------------------------------------------------------------- #
 
 def _trusted_step(arena, x, w):
-    """Stand-in model step: enough chained ops that eager execution pays
-    one dispatch per op while the compiled path pays one per step."""
+    """Stand-in model step: enough chained ops that the compiled path
+    pays one dispatch for many ops."""
     h = x
     for _ in range(6):
         h = jnp.tanh(h @ w) + x
@@ -361,28 +355,18 @@ def _trusted_rate(mgr, client, x, w, rounds: int) -> float:
 
 
 def _bench_trusted_jit(out: List[str]) -> None:
-    setups = {}
-    for jit in (False, True):
-        mgr = GuardianManager(total_slots=1 << 10, jit_trusted=jit)
-        mgr.register_trusted_kernel("step", _trusted_step)
-        c = mgr.register_tenant("svc", 256)
-        x = jnp.ones((16, 64), jnp.float32)
-        w = jnp.asarray(np.linspace(-1, 1, 64 * 64, dtype=np.float32)
-                        .reshape(64, 64))
-        setups[jit] = (mgr, c, x, w)
-        _trusted_rate(mgr, c, x, w, 4)              # warmup + compile
-    samples = {False: [], True: []}
-    for _ in range(REPS):
-        for jit, (mgr, c, x, w) in setups.items():
-            samples[jit].append(_trusted_rate(mgr, c, x, w, N_ROUNDS))
-    rates = {jit: float(np.median(v)) for jit, v in samples.items()}
-    win = rates[True] / rates[False]
-    out.append(f"sched.jit.eager,{1e6 / rates[False]:.2f},"
-               f"steps_per_s={rates[False]:.0f}")
-    out.append(f"sched.jit.compiled,{1e6 / rates[True]:.2f},"
-               f"steps_per_s={rates[True]:.0f};speedup={win:.2f}x")
-    for line in out[-2:]:
-        print(line)
+    mgr = GuardianManager(total_slots=1 << 10)
+    mgr.register_trusted_kernel("step", _trusted_step)
+    c = mgr.register_tenant("svc", 256)
+    x = jnp.ones((16, 64), jnp.float32)
+    w = jnp.asarray(np.linspace(-1, 1, 64 * 64, dtype=np.float32)
+                    .reshape(64, 64))
+    _trusted_rate(mgr, c, x, w, 4)                  # warmup + compile
+    rate = float(np.median([_trusted_rate(mgr, c, x, w, N_ROUNDS)
+                            for _ in range(REPS)]))
+    out.append(f"sched.jit.compiled,{1e6 / rate:.2f},"
+               f"steps_per_s={rate:.0f}")
+    print(out[-1])
 
 
 # --------------------------------------------------------------------- #
@@ -399,24 +383,17 @@ def _micro_serve_cfg():
     return get_config("stablelm-3b").reduced()
 
 
-#: multiengine configurations: (shared manager+fusion?, compiled steps?)
-_ME_MODES = {"eager": (False, False),
-             "independent": (False, True),
-             "fused": (True, True)}
+#: multiengine configurations: the engines share one manager and fuse?
+_ME_MODES = {"independent": False, "fused": True}
 
 
 def _make_engines(cfg, n_eng: int, mode: str):
     from repro.launch.serve import ServeEngine, make_shared_manager
 
-    shared, jit = _ME_MODES[mode]
-    if shared:
-        mgr = make_shared_manager(n_eng, max_batch=2, jit_trusted=jit)
-        engines = [ServeEngine(cfg, max_batch=2, max_len=16, manager=mgr)
-                   for _ in range(n_eng)]
-    else:
-        engines = [ServeEngine(cfg, max_batch=2, max_len=16,
-                               jit_steps=jit)
-                   for _ in range(n_eng)]
+    shared = _ME_MODES[mode]
+    mgr = make_shared_manager(n_eng, max_batch=2) if shared else None
+    engines = [ServeEngine(cfg, max_batch=2, max_len=16, manager=mgr)
+               for _ in range(n_eng)]
     for i, eng in enumerate(engines):
         eng.register_tenant(f"b{i}" if shared else "b0", 2)
     return engines
@@ -424,23 +401,19 @@ def _make_engines(cfg, n_eng: int, mode: str):
 
 def _serve_round(engines, mode: str, prompts) -> float:
     """Submit one request per engine, serve a round of tokens, return
-    engine-steps/sec (prefill + decodes, summed over engines).  The
-    eager plane is orders of magnitude slower per step (that is the
-    point), so it gets a short window — the per-step rate is what's
-    compared."""
+    engine-steps/sec (prefill + decodes, summed over engines)."""
     from repro.launch.serve import serve_engines
 
-    shared = _ME_MODES[mode][0]
-    tokens = 2 if mode == "eager" else SERVE_TOKENS
+    shared = _ME_MODES[mode]
     for i, eng in enumerate(engines):
         eng.submit(f"b{i}" if shared else "b0", prompts[i])
-    steps = len(engines) * (1 + tokens)
+    steps = len(engines) * (1 + SERVE_TOKENS)
     t0 = time.perf_counter()
     if shared:
-        serve_engines(engines, max_new_tokens=tokens)
+        serve_engines(engines, max_new_tokens=SERVE_TOKENS)
     else:
         for eng in engines:
-            eng.run(max_new_tokens=tokens)
+            eng.run(max_new_tokens=SERVE_TOKENS)
     return steps / (time.perf_counter() - t0)
 
 
@@ -454,10 +427,8 @@ def _bench_multiengine(out: List[str]) -> None:
         for mode, engines in setups.items():        # warmup + compile
             _serve_round(engines, mode, prompts)
         samples = {m: [] for m in _ME_MODES}
-        for rep in range(SERVE_REPS):
+        for _ in range(SERVE_REPS):
             for mode, engines in setups.items():
-                if mode == "eager" and rep >= 2:
-                    continue        # ~0.5s/step: two reps are plenty
                 samples[mode].append(
                     _serve_round(engines, mode, prompts))
         # best-of-reps: the serve rounds are short timed windows, so an
@@ -467,20 +438,15 @@ def _bench_multiengine(out: List[str]) -> None:
         rates = {m: float(np.max(v)) for m, v in samples.items()}
         width = setups["fused"][0].manager.scheduler.stats \
             .mean_batch_width
-        win = rates["fused"] / rates["eager"]
-        vs_jit = rates["fused"] / rates["independent"]
-        out.append(f"sched.multiengine.eager.{n_eng}e,"
-                   f"{1e6 / rates['eager']:.2f},"
-                   f"steps_per_s={rates['eager']:.0f}")
+        win = rates["fused"] / rates["independent"]
         out.append(f"sched.multiengine.independent.{n_eng}e,"
                    f"{1e6 / rates['independent']:.2f},"
                    f"steps_per_s={rates['independent']:.0f}")
         out.append(f"sched.multiengine.fused.{n_eng}e,"
                    f"{1e6 / rates['fused']:.2f},"
                    f"steps_per_s={rates['fused']:.0f}"
-                   f";mean_width={width:.1f};speedup={win:.2f}x"
-                   f";vs_jit={vs_jit:.2f}x")
-        for line in out[-3:]:
+                   f";mean_width={width:.1f};speedup={win:.2f}x")
+        for line in out[-2:]:
             print(line)
 
 
@@ -495,7 +461,7 @@ def main(out: List[str]):
           "(same kernels, same tenants; fused steps carry per-row "
           "(base, mask) rows — BITWISE — or (base, size, m, s) magic "
           "rows — MODULO — one binary, no per-tenant recompiles); "
-          "sched.jit.* = compiled vs eager trusted steps; "
+          "sched.jit.compiled = compiled trusted steps; "
           "sched.multiengine.* = N engines fused on one manager vs N "
           "independent engines")
 

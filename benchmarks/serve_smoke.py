@@ -1,8 +1,8 @@
-"""Serve-path smoke timings: the four CI serve configurations as bench
+"""Serve-path smoke timings: the three CI serve configurations as bench
 rows, so the end-to-end serving hot path (submit -> trusted jit step ->
 fused drain -> fence verify) is *gated*, not just exercised.
 
-CI's tier-1 job runs the same four configs via ``repro.launch.serve``
+CI's tier-1 job runs the same three configs via ``repro.launch.serve``
 with ``--bench-out``; this suite mirrors them through the same
 entrypoint so a local ``benchmarks.run serve_smoke`` reproduces the CI
 rows (``serve.smoke.*`` in ``results/bench.csv``) byte-for-byte in
@@ -10,7 +10,7 @@ shape.  Per-token wall time includes trace/compile (cold start, fresh
 engines per config) — the gate normalizes by the median fresh/baseline
 ratio, so only *relative* drift between configs fires it.
 
-Not part of ``--quick``: four cold-start serves are ~a minute of wall
+Not part of ``--quick``: three cold-start serves are ~a minute of wall
 time, and the quick set must stay fast enough to run on every push.
 """
 
@@ -29,9 +29,6 @@ CONFIGS = [
     ("baseline",
      ["--arch", "stablelm-3b", "--reduced", "--tenants", "2",
       "--requests", "2", "--tokens", "4"]),
-    ("eager",
-     ["--arch", "stablelm-3b", "--reduced", "--tenants", "2",
-      "--requests", "2", "--tokens", "4", "--no-jit"]),
     ("multi_engine",
      ["--arch", "stablelm-3b", "--reduced", "--engines", "2",
       "--tenants", "1", "--requests", "2", "--tokens", "4"]),

@@ -152,10 +152,8 @@ class _KernelEntry:
         default_factory=dict)         # (mode, static_positions) -> jitted
     #: framework-plane kernels (serving-engine steps): already fenced
     #: internally via a GuardSpec built from the manager's fence table,
-    #: so the sandboxer is skipped — never specialized per policy.  With
-    #: ``jit_trusted`` the launch runs through a compiled (and, across
-    #: engines, fused) step; ``jit_trusted=False`` restores the eager
-    #: unfused fallback.
+    #: so the sandboxer is skipped — never specialized per policy.  The
+    #: launch runs through a compiled (and, across engines, fused) step.
     trusted: bool = False
     #: fn-arg positions (arena = 0) whose buffers the jitted trusted step
     #: may donate — consumed-once operands like the engine's KV cache
@@ -221,7 +219,6 @@ class GuardianManager:
         max_tenants: int = 64,
         quarantine_policy: Optional[QuarantinePolicy] = None,
         quarantine_poll_every: int = 1,
-        jit_trusted: bool = True,
         jit_cache_capacity: int = 64,
         lookahead_cycles: int = 0,
         adaptive_lookahead: bool = False,
@@ -234,11 +231,6 @@ class GuardianManager:
         self.mode = mode
         self.standalone_fast_path = standalone_fast_path
         self.batch_launches = batch_launches
-        #: compile trusted (framework-plane) steps instead of executing
-        #: them eagerly, and let compatible trusted steps from multiple
-        #: serve engines fuse into one device step.  False restores the
-        #: eager per-launch fallback (bit-identical by regression test).
-        self.jit_trusted = jit_trusted
         #: LRU capacity of each kernel entry's fenced jit cache (ROADMAP:
         #: symbol-cache growth under many-kernel churn)
         self.jit_cache_capacity = jit_cache_capacity
@@ -839,12 +831,10 @@ class GuardianManager:
 
         The jaxpr sandboxer is skipped: the step is itself a fused
         multi-row program whose rows the engine fences, so wrapping it in
-        the scheduler's row fencing would double-fence.  With
-        ``jit_trusted`` (the default) the launch runs through a compiled
-        step keyed by its operand signature, and compatible trusted steps
-        from *different* serve engines fuse into one device step; with
-        ``jit_trusted=False`` it executes eagerly and unfused (the
-        bit-identical fallback).  Trusted kernels still ride the queues
+        the scheduler's row fencing would double-fence.  The launch runs
+        through a compiled step keyed by its operand signature, and
+        compatible trusted steps from *different* serve engines fuse into
+        one device step.  Trusted kernels still ride the queues
         and the scheduler drain — ordering, quarantine drops and launch
         telemetry are shared — and are never batched with tenant kernels
         (the signature includes the kernel name).
@@ -1065,9 +1055,7 @@ class GuardianManager:
         req = LaunchRequest(tenant_id=tenant_id, name=name,
                             policy=self._effective_policy(tenant_id),
                             entry=entry, part=part,
-                            call_args=(*ptr_args, *args),
-                            trusted_fusable=entry.trusted
-                            and self.jit_trusted)
+                            call_args=(*ptr_args, *args))
         if enqueue or self.mode is SharingMode.SPATIAL:
             self._enqueue(tenant_id, "launch", (req,))
             # the request doubles as the result handle: req.result holds
@@ -1092,8 +1080,7 @@ class GuardianManager:
         req = LaunchRequest(
             tenant_id=tenant_id, name=name,
             policy=self._effective_policy(tenant_id),
-            entry=entry, part=part, call_args=(),
-            trusted_fusable=entry.trusted and self.jit_trusted)
+            entry=entry, part=part, call_args=())
         self.scheduler._execute([req])
         return req.result
 
@@ -1110,17 +1097,12 @@ class GuardianManager:
 
         if entry.trusted:
             # framework step: internally fenced, no augmentation — jitted
-            # (keyed by operand signature, pool/arena donated) unless
-            # jit_trusted is off, in which case the eager fallback runs —
-            # see register_trusted_kernel
+            # (keyed by operand signature, pool/arena donated; a verify=True
+            # step owes its proof before it compiles) — see
+            # register_trusted_kernel
             t1 = time.perf_counter_ns()
-            if self.jit_trusted:
-                fn = self._trusted_exec(entry, req.call_args,
-                                        arg_sig=req.signature[2])
-            else:
-                if entry.verify:     # eager path still owes the proof
-                    self._verify_trusted(entry, req.call_args)
-                fn = entry.fn
+            fn = self._trusted_exec(entry, req.call_args,
+                                    arg_sig=req.signature[2])
             if entry.pool_arena is None:
                 new_arena, out = fn(self.arena.buf, *req.call_args)
             else:

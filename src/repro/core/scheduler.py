@@ -53,9 +53,6 @@ Non-fusable launches degrade gracefully to the per-launch path:
 
 * NONE      — standalone fast path (§4.2.3): a single tenant gets the
               native binary, no batching machinery on the hot path.
-* trusted, with ``jit_trusted=False`` — the eager fallback: steps ride
-              the same drain for ordering/quarantine but execute eagerly
-              and unfused via the per-launch path.
 
 Fairness: requests are taken strictly in arrival order (the manager's
 round-robin cycle order).  A request that cannot join the open batch
@@ -196,10 +193,6 @@ class LaunchRequest:
     #: callers read it after the drain — how the serving engine gets its
     #: step logits back through the shared scheduler)
     result: Any = dataclasses.field(default=None, repr=False)
-    #: trusted entries fuse only when the manager jits the trusted path
-    #: (set at launch time from ``manager.jit_trusted``): fusing means
-    #: tracing N steps into one binary, which the eager fallback must not
-    trusted_fusable: bool = False
     #: scheduler drain-cycle stamp, set at submit (-1 = never submitted,
     #: i.e. executed directly through the per-launch path)
     submit_cycle: int = dataclasses.field(default=-1, repr=False)
@@ -214,10 +207,8 @@ class LaunchRequest:
 
     @property
     def fusable(self) -> bool:
-        if getattr(self.entry, "trusted", False):
-            return self.trusted_fusable
-        return self.policy in (FencePolicy.BITWISE, FencePolicy.CHECK,
-                               FencePolicy.MODULO)
+        return getattr(self.entry, "trusted", False) or self.policy in (
+            FencePolicy.BITWISE, FencePolicy.CHECK, FencePolicy.MODULO)
 
     def repolicy(self, policy: FencePolicy) -> None:
         """Re-resolve the fence policy at drain time.  The effective policy
@@ -765,10 +756,7 @@ class BatchedLaunchScheduler:
                       width=len(batch),
                       tenants=",".join(r.tenant_id for r in batch))
         if getattr(batch[0].entry, "trusted", False):
-            # internally-fenced engine step: jitted width-N fusion when the
-            # manager compiles the trusted path, else the eager width-1
-            # per-launch fallback (trusted_fusable=False keeps eager
-            # batches at width 1)
+            # internally-fenced engine step: jitted width-N fusion
             self._execute_trusted(batch)
             return
         if batch[0].policy is FencePolicy.CHECK:
@@ -859,9 +847,9 @@ class BatchedLaunchScheduler:
     # ------------------------------------------------------------------ #
     def _execute_trusted(self, batch: List[LaunchRequest]) -> None:
         """Trusted (framework-plane) dispatch.  Width 1 goes through the
-        manager's per-launch path (jitted there when ``jit_trusted``);
-        width N traces every engine's step into one compiled device step —
-        the multi-engine fused decode.  The arena threads through rows
+        manager's per-launch path (jitted there); width N traces every
+        engine's step into one compiled device step — the multi-engine
+        fused decode.  The arena threads through rows
         untouched (trusted steps carry their own internal fences), so the
         fused program is exactly the sequential composition of the solo
         steps."""

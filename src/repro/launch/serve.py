@@ -14,8 +14,12 @@ policy of its own**:
   the shared :class:`BatchedLaunchScheduler` — serving traffic and raw
   tenant launches ride one dispatch layer.  Steps **compile**: the
   manager's jitted trusted path runs each step as one device program
-  (params/cache/guard are operands, never closure constants), with the
-  eager path kept as a bit-identical ``--no-jit`` fallback;
+  (params/cache/guard are operands, never closure constants);
+* the engine asks the model only through its API (``models/api.py``:
+  step identity, fence sizes, prefill input, key counts) and the cache
+  only through ``models/kvcache.py``'s walks over the fields each cache
+  type declares, so a new model touches neither this module nor
+  :mod:`repro.launch.steps`;
 * N engines may **share one manager** (``manager=`` / ``name=``): their
   tenants partition one global slot space, and the scheduler coalesces
   compatible steps from different engines into one fused device step —
@@ -56,12 +60,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import FULL, SLIDING, get_config
+from repro.configs import get_config
 from repro.core.arena import PagePool, PoolArena
 from repro.core.elastic import ElasticError, ResizeEvent
 from repro.core.fence import FenceParams, FencePolicy
 from repro.core.manager import GuardianManager
-from repro.core.partition import OutOfArenaMemory
+from repro.core.partition import OutOfArenaMemory, next_pow2
 from repro.core.quarantine import QuarantinePolicy, TenantState
 from repro.core.scheduler import round_robin_interleave
 from repro.core.telemetry import (
@@ -83,8 +87,6 @@ from repro.launch.compile_cache import place_compile_cache
 from repro.launch.steps import (
     build_pool_relocation_step,
     build_trusted_serve_steps,
-    join_cache_pool,
-    split_cache_pool,
 )
 from repro.models import get_model
 from repro.models import kvcache as KV
@@ -96,10 +98,6 @@ from repro.models.guard import GuardSpec
 #: Engines sharing a manager suffix it (``__scratch.e1``, ...) so each
 #: engine gets its own scratch partition and launch queue.
 ENGINE_TENANT = "__scratch"
-#: per-tenant counters of the keys attended in sliding-window layers and
-#: in full layers, from the rows' lengths (``ServeEngine._count_keys``)
-KEYS_WINDOW = "attn.keys_window"
-KEYS_FULL = "attn.keys_full"
 
 
 @dataclasses.dataclass
@@ -195,7 +193,7 @@ def make_shared_manager(n_engines: int, max_batch: int = 8,
     page space backing one shared physical page pool."""
     unit = max(max_len // KV.PAGE_SIZE, 1) if paged else 1
     return GuardianManager(
-        total_slots=n_engines * 2 * _pow2(max_batch) * unit,
+        total_slots=n_engines * 2 * next_pow2(max_batch) * unit,
         policy=policy, standalone_fast_path=False, **kw)
 
 
@@ -222,7 +220,6 @@ class ServeEngine:
                  quarantine_policy: Optional[QuarantinePolicy] = None,
                  manager: Optional[GuardianManager] = None,
                  name: Optional[str] = None,
-                 jit_steps: bool = True,
                  telemetry: bool = True,
                  paged: bool = False,
                  max_inflight: Optional[int] = None,
@@ -237,56 +234,45 @@ class ServeEngine:
         self.max_inflight = min(max_inflight or max_batch, max_batch)
         self.temperature = temperature
         self.top_k = top_k
-        if paged and cfg.family not in ("dense", "moe"):
+        if paged and not self.api.global_paged:
             raise ValueError(
                 "paged serve mode needs the global paged KV layout "
-                f"(dense/moe transformers); {cfg.family!r} engines use "
-                "the slab layout")
+                f"(dense/moe transformers); {cfg.name!r} serves from the "
+                "slab layout")
         #: paged mode: virtual pages per request (= max_len / page size)
         self.pages_per_req = max(max_len // KV.PAGE_SIZE, 1)
-        slot_unit = self.pages_per_req if paged else 1
         self.params = self.api.init(jax.random.PRNGKey(seed))
         if manager is None:
-            # pool = 2x the batch slots: the upper half is the engine's
-            # scratch partition where idle batch rows park.
-            # standalone_fast_path=False: a guarded engine always fences,
-            # even with a single tenant (bit-identical solo vs shared).
-            n_slots = 2 * _pow2(max_batch) * slot_unit
-            self.manager = GuardianManager(
-                total_slots=n_slots, policy=policy,
-                standalone_fast_path=False,
-                quarantine_policy=quarantine_policy,
-                jit_trusted=jit_steps,
+            # an engine of its own is the one engine of a shared manager,
+            # under the unsuffixed scratch tenant
+            manager = make_shared_manager(
+                1, max_batch=max_batch, policy=policy, paged=paged,
+                max_len=max_len, quarantine_policy=quarantine_policy,
                 telemetry=telemetry)
-            # paged: parked rows only need ONE request's worth of scratch
-            # page ids (they all resolve to the allocator-owned garbage
-            # page); slab: half the pool is the per-row scratch slots
-            scratch_slots = _pow2(self.pages_per_req) if paged \
-                else n_slots // 2
             self.engine_tenant = ENGINE_TENANT
         else:
-            # fencing, containment and step compilation are manager-wide
+            # fencing, containment and telemetry are manager-wide
             # concerns: refuse per-engine overrides instead of silently
             # ignoring them (configure them on the shared manager)
             if (policy is not FencePolicy.BITWISE
-                    or quarantine_policy is not None or not jit_steps
-                    or not telemetry):
+                    or quarantine_policy is not None or not telemetry):
                 raise ValueError(
-                    "policy/quarantine_policy/jit_steps/telemetry are "
-                    "owned by the shared GuardianManager; configure them "
-                    "on the manager (see make_shared_manager) instead of "
-                    "on a co-hosted ServeEngine")
-            self.manager = manager
-            n_slots = manager.bounds.total_slots
-            scratch_slots = _pow2(self.pages_per_req) if paged \
-                else _pow2(max_batch)
-            policy = manager.policy
+                    "policy/quarantine_policy/telemetry are owned by the "
+                    "shared GuardianManager; configure them on the "
+                    "manager (see make_shared_manager) instead of on a "
+                    "co-hosted ServeEngine")
             if name is None:
                 name = "e%d" % sum(
                     1 for t in manager.bounds.tenants()
                     if t.startswith(ENGINE_TENANT))
             self.engine_tenant = f"{ENGINE_TENANT}.{name}"
-        self.policy = policy
+        self.manager = manager
+        self.policy = manager.policy
+        n_slots = manager.bounds.total_slots
+        # paged: parked rows only need ONE request's worth of scratch page
+        # ids (they all resolve to the allocator-owned garbage page); slab:
+        # a scratch slot per batch row
+        scratch_slots = next_pow2(self.pages_per_req if paged else max_batch)
         # ONE pool for the manager's full slot space: the pool tensors are
         # adopted by the manager as a PoolArena (the manager is the only
         # entity with device access), so co-hosted engines of the same
@@ -297,23 +283,14 @@ class ServeEngine:
         # so a wider pool would store nothing more and move twice the bytes.
         kv_dtype = jnp.dtype(cfg.dtype)
         if paged:
-            # Geometry-and-dtype pool key: the global page pool is one
-            # (L, P, page, KH, D) tensor — engines serving *different*
-            # model shapes with the same KV geometry and dtype share it
-            # (and the manager drain), keeping model identity in the step
-            # key so their step symbols stay distinct.
-            self._n_phys = _pow2(n_slots)
-            pool_key = (f"paged:L{cfg.decoder_layers}:kh{cfg.n_kv_heads}:"
-                        f"d{cfg.head_dim}:pg{KV.PAGE_SIZE}:"
-                        f"P{self._n_phys}:{kv_dtype.name}")
-            step_key = (f"{cfg.name}:{cfg.family}:{cfg.n_layers}x"
-                        f"{cfg.d_model}v{cfg.vocab}{_kinds_key(cfg)}:"
-                        f"{pool_key}")
+            # engines with equal KV geometry and dtype share the global
+            # page pool; the model's identity stays in the step names
+            n_phys = next_pow2(n_slots)
+            pool_key = KV.global_pool_key(cfg, n_phys, kv_dtype)
+            step_key = f"{self.api.step_id}:{pool_key}"
         else:
-            pool_key = (f"{cfg.name}:{cfg.family}:{cfg.n_layers}x"
-                        f"{cfg.d_model}v{cfg.vocab}{_kinds_key(cfg)}:"
-                        f"s{n_slots}:l{max_len}:{kv_dtype.name}")
-            step_key = None
+            pool_key = step_key = (f"{self.api.step_id}:s{n_slots}:"
+                                   f"l{max_len}:{kv_dtype.name}")
         self._steps = build_trusted_serve_steps(
             self.api, pool_key, step_key=step_key,
             temperature=temperature, top_k=top_k,
@@ -323,19 +300,14 @@ class ServeEngine:
         # slot ids, seq lens, page tables — is slot-count independent), so
         # the dominant allocation happens once per pool, not once per
         # engine.
-        adopted = self._steps.pool_name in self.manager.arenas
+        size = 1 if self._steps.pool_name in self.manager.arenas else None
         if paged:
-            cache = KV.init_global_kv_cache(
-                cfg, max_batch, max_len, 1 if adopted else self._n_phys,
-                dtype=kv_dtype)
-        elif cfg.family == "ssm":
-            cache = self.api.init_cache(max_batch,
-                                        slots=1 if adopted else n_slots)
+            cache = KV.init_global_kv_cache(cfg, max_batch, max_len,
+                                            size or n_phys, dtype=kv_dtype)
         else:
-            cache = self.api.init_cache(max_batch, max_len,
-                                        dtype=kv_dtype,
-                                        slots=1 if adopted else n_slots)
-        pool, self._meta = split_cache_pool(cache)
+            cache = self.api.init_cache(max_batch, max_len, dtype=kv_dtype,
+                                        slots=size or n_slots)
+        pool, self._meta = KV.split_pool(cache)
         self._client = self.manager.register_tenant(self.engine_tenant,
                                                     scratch_slots)
         self._scratch = self.manager.bounds.lookup(self.engine_tenant)
@@ -355,7 +327,13 @@ class ServeEngine:
             # one virt->phys allocator per pool arena, shared by every
             # co-hosted engine; virt space = the manager's page-granular
             # slot space, phys space = the pool tensor's page axis
-            self._pool.pages = PagePool(self._n_phys, n_slots)
+            self._pool.pages = PagePool(n_phys, n_slots)
+        #: the guard's static fences: the model's, and the page clamp of
+        #: the pool (a slab's pages, or every page of the global pool)
+        self._fences = {space: FenceParams(base=0, size=n)
+                        for space, n in self.api.fence_sizes.items()}
+        self._fences["page"] = FenceParams(
+            base=0, size=next_pow2(KV.pages_per_slot(self.cache)))
         tel = self.manager.telemetry
         if paged and tel.enabled:
             # the pool as allocated, against the logical KV of one token
@@ -376,12 +354,6 @@ class ServeEngine:
         self._spans: Dict[int, Any] = {}
         self._rid = 0
         self.decode_steps = 0
-        kinds = cfg.layer_kinds
-        #: (counter, layers of the kind, window) for _count_keys
-        self._key_counters = [
-            (name, kinds.count(kind), window) for name, kind, window in (
-                (KEYS_WINDOW, SLIDING, cfg.attn_window),
-                (KEYS_FULL, FULL, 0)) if kinds.count(kind)]
         #: sampled decode steps thread a fresh PRNG key per cycle
         self._sample_key = jax.random.PRNGKey(seed ^ 0x5EED) \
             if temperature > 0 else None
@@ -396,19 +368,11 @@ class ServeEngine:
         joined with this engine's per-batch meta.  Assignment splits the
         value back (pool half commits to the shared arena — visible to
         every co-hosted engine)."""
-        return join_cache_pool(self._pool.buf, self._meta)
+        return KV.join_pool(self._pool.buf, self._meta)
 
     @cache.setter
     def cache(self, value):
-        self._pool.buf, self._meta = split_cache_pool(value)
-
-    def _pool_slots(self) -> int:
-        c = self.cache
-        if hasattr(c, "k"):
-            return c.k.shape[1]
-        if hasattr(c, "pools"):
-            return next(iter(c.pools.values())).shape[1]
-        return c.kv.k.shape[1]
+        self._pool.buf, self._meta = KV.split_pool(value)
 
     # ------------------------------------------------------------------ #
     # Tenant lifecycle (all state on the manager)                        #
@@ -531,29 +495,24 @@ class ServeEngine:
             # the same whole-pool scrub.  This zeroing is the KV-leak
             # barrier — the reclaimed slots must hand over empty.
             part = self.manager.bounds.lookup(tenant_id)
+            ids = range(part.base, part.end)
             if self.paged:
                 # translate the virtual extent to its physical pages
                 # BEFORE releasing them (the map rows zero on release),
                 # then zero those pages — the KV-leak barrier for the
                 # global pool
                 pages = self._pool.pages
-                pm = pages.page_map
-                phys = tuple(int(pm[v]) for v in
-                             range(part.base, part.base + part.size)
-                             if int(pm[v]))
+                ids = tuple(int(pages.page_map[v]) for v in ids
+                            if int(pages.page_map[v]))
                 pages.release_extent(tenant_id)
-                if self._in_run:
-                    self._pending_scrubs.append(("phys", phys))
-                else:
-                    self.cache = _scrub_phys_pages(self.cache, phys)
-            elif self._in_run:
+            if self._in_run:
                 # run() holds a newer local cache that overwrites
                 # self.cache at run-end (and, under donation, may have
                 # consumed these very buffers) — scrub the committed
                 # cache at run-end instead
-                self._pending_scrubs.append((part.base, part.size))
+                self._pending_scrubs.append(ids)
             else:
-                self.cache = _scrub_slots(self.cache, part.base, part.size)
+                self.cache = KV.scrub(self.cache, ids)
         if not state.admissible:
             dropped = [r.rid for r in self._requests
                        if r.tenant == tenant_id and not r.done]
@@ -684,40 +643,24 @@ class ServeEngine:
         # row-mixed policies only when some tenant actually diverges from
         # the engine default (the homogeneous path stays bit-identical)
         mixed = bool((pol != self.policy.code).any())
-        row_policy = jnp.asarray(pol) if mixed else None
-        if self.paged:
-            # kv space = per-row *virtual page* extents (the fence-table
-            # rows ARE page extents in paged mode); virt->phys goes
-            # through the manager-owned map, then the "page" clamp keeps
-            # even a corrupted map entry inside the pool tensor
-            return GuardSpec(
-                policy=self.policy,
-                vocab=FenceParams(base=0, size=_pow2(self.cfg.vocab)),
-                kv=slot_params,
-                expert=(FenceParams(base=0, size=_pow2(
-                    self.cfg.moe.held)) if self.cfg.moe else None),
-                page=FenceParams(base=0, size=self._n_phys),
-                row_policy=row_policy,
-                # a snapshot: the host rewrites the map in place at later
-                # cycle boundaries (join, leave, elastic rebase) while this
-                # step may still wait in the device queue, and the CPU
-                # backend adopts an aligned host array without copying
-                # it, so the step would read the map as it is when it
-                # runs, not as it was when it was handed over
-                page_map=jnp.asarray(self._pool.pages.page_map.copy()),
-            )
-        pages = self.cache.kv.pages_per_slot if hasattr(self.cache, "kv") \
-            else (self.cache.pages_per_slot if hasattr(self.cache, "k")
-                  else 1)
         return GuardSpec(
             policy=self.policy,
-            vocab=FenceParams(base=0, size=_pow2(self.cfg.vocab)),
+            # kv: slot extents, or in paged mode the rows' *virtual page*
+            # extents (the fence-table rows ARE page extents there)
             kv=slot_params,
             state=slot_params,
-            expert=(FenceParams(base=0, size=_pow2(
-                self.cfg.moe.held)) if self.cfg.moe else None),
-            page=FenceParams(base=0, size=_pow2(max(pages, 1))),
-            row_policy=row_policy,
+            row_policy=jnp.asarray(pol) if mixed else None,
+            # paged: virt->phys goes through the manager-owned map, then
+            # the "page" clamp keeps even a corrupted map entry inside the
+            # pool tensor.  The map is a snapshot: the host rewrites it in
+            # place at later cycle boundaries (join, leave, elastic rebase)
+            # while this step may still wait in the device queue, and the
+            # CPU backend adopts an aligned host array without copying it,
+            # so the step would read the map as it is when it runs, not as
+            # it was when it was handed over
+            page_map=(jnp.asarray(self._pool.pages.page_map.copy())
+                      if self.paged else None),
+            **self._fences,
         )
 
     def _select_rows(self) -> List[Request]:
@@ -797,15 +740,10 @@ class ServeEngine:
         slot_ids = np.full((B,), self._scratch.base, np.int32)
         for i, r in enumerate(rows):
             slot_ids[i] = r.slot
-        meta = _reset_seq_lens(self._meta_with_slots(jnp.asarray(slot_ids)))
+        meta = KV.reset_seq_lens(KV.with_slot_ids(self._meta,
+                                                  jnp.asarray(slot_ids)))
         guard = self._guard_for_rows(rows + [None] * (B - len(rows)))
-
-        if self.cfg.family == "encdec":
-            batch = {"src": jnp.zeros(
-                (B, 16, self.cfg.d_model), jnp.float32),
-                "tgt": jnp.asarray(toks)}
-        else:
-            batch = {"tokens": jnp.asarray(toks)}
+        batch = self.api.prefill_input(jnp.asarray(toks))
         has_check = any(
             self.manager.policy_of(r.tenant) is FencePolicy.CHECK
             for r in rows)
@@ -881,24 +819,9 @@ class ServeEngine:
         return out
 
     def _apply_pending_scrubs(self) -> None:
-        for item in self._pending_scrubs:
-            if item and item[0] == "phys":
-                self.cache = _scrub_phys_pages(self.cache, item[1])
-            else:
-                self.cache = _scrub_slots(self.cache, *item)
+        for ids in self._pending_scrubs:
+            self.cache = KV.scrub(self.cache, ids)
         self._pending_scrubs.clear()
-
-    def _meta_with_slots(self, slot_ids):
-        c = self._meta
-        if hasattr(c, "slot_ids"):
-            return dataclasses.replace(c, slot_ids=slot_ids)
-        if hasattr(c, "kv"):   # hybrid / encdec
-            kv = dataclasses.replace(c.kv, slot_ids=slot_ids)
-            if hasattr(c, "state"):
-                st = dataclasses.replace(c.state, slot_ids=slot_ids)
-                return dataclasses.replace(c, kv=kv, state=st)
-            return dataclasses.replace(c, kv=kv)
-        return c
 
     # -- continuous batching (paged mode; serve_continuous drives) ----- #
     def _admissible(self, tenant: str) -> bool:
@@ -1112,7 +1035,7 @@ class ServeEngine:
                     toks[i, :len(st.rows[i].prompt)] = st.rows[i].prompt
                 with tel.span(SPAN_SERVE_META):
                     meta = self._cont_meta(st, set(joiners))
-                batch = {"tokens": jnp.asarray(toks)}
+                batch = self.api.prefill_input(jnp.asarray(toks))
                 with tel.span(SPAN_SERVE_LAUNCH):
                     pre_req = self._client.launch_kernel(
                         self._steps.prefill_name,
@@ -1152,14 +1075,10 @@ class ServeEngine:
         return pre_req, dec_req, joiners, continuers, plen
 
     def _count_keys(self, tenant: str, start: int, stop: int) -> None:
-        """Count the keys that queries at positions [start, stop) attend,
-        in the sliding and in the full layers (every position is written,
-        pads too)."""
-        for name, layers, window in self._key_counters:
-            self.manager.telemetry.registry.inc(
-                name, layers * (_keys_upto(stop, window)
-                                - _keys_upto(start, window)),
-                tenant=tenant)
+        """Count the keys that queries at positions [start, stop) attend
+        (``ModelAPI.key_counts``)."""
+        for name, n in self.api.key_counts(start, stop).items():
+            self.manager.telemetry.registry.inc(name, n, tenant=tenant)
 
     def _cont_finish(self, st: _ContState, pre_req, dec_req,
                      joiners: List[int], continuers: List[int],
@@ -1402,83 +1321,6 @@ def _cont_cycles(engines: List["ServeEngine"], states: List[_ContState],
                                  time.thread_time_ns() - cpu0)
 
 
-def _kinds_key(cfg) -> str:
-    """The step-key part that tells layer patterns and expert shares
-    apart: empty for a model whose layers are all alike and hold every
-    expert."""
-    key = ""
-    if cfg.layer_types:
-        key += ":" + "".join(k[0] for k in cfg.layer_period) + \
-            f"w{cfg.attn_window}" + ("y" if cfg.yarn else "")
-    if cfg.moe and cfg.moe.held != cfg.moe.num_experts:
-        key += f":e{cfg.moe.expert_base}+{cfg.moe.held}of" \
-            f"{cfg.moe.num_experts}"
-    return key
-
-
-def _keys_upto(n: int, window: int) -> int:
-    """Keys that queries at positions 0..n-1 attend under causal attention
-    over the last ``window`` positions (0: all of them)."""
-    if not window or n <= window:
-        return n * (n + 1) // 2
-    return window * (window + 1) // 2 + (n - window) * window
-
-
-def _pow2(n: int) -> int:
-    return 1 << max(int(n - 1).bit_length(), 0) if n > 1 else 1
-
-
-def _reset_seq_lens(meta):
-    """A lockstep wave prefills EVERY row, so each row's sequence starts
-    at 0 — without this an engine reused across run() calls would carry
-    the previous wave's seq_lens into the new prefill (stale write
-    positions + attention over dead tokens)."""
-    if hasattr(meta, "seq_lens"):
-        return dataclasses.replace(
-            meta, seq_lens=jnp.zeros_like(meta.seq_lens))
-    if hasattr(meta, "kv") and hasattr(meta.kv, "seq_lens"):
-        kv = dataclasses.replace(
-            meta.kv, seq_lens=jnp.zeros_like(meta.kv.seq_lens))
-        return dataclasses.replace(meta, kv=kv)
-    return meta
-
-
-def _scrub_phys_pages(cache, phys):
-    """Zero a set of *physical* pages of the global paged pool (axis 1 of
-    the 5-dim k/v tensors) — the paged-mode eviction scrub."""
-    if not phys:
-        return cache
-    idx = jnp.asarray(tuple(phys), jnp.int32)
-
-    def zero(arr):
-        z = jnp.zeros((arr.shape[0], len(phys), *arr.shape[2:]), arr.dtype)
-        return arr.at[:, idx].set(z)
-
-    return dataclasses.replace(cache, k=zero(cache.k), v=zero(cache.v))
-
-
-def _scrub_slots(cache, base: int, size: int):
-    """Zero a slot range [base, base+size) across every pool tensor of a
-    cache pytree (axis 1 is the shared slot axis in all cache layouts —
-    see kvcache.PagedKVCache / StateCache)."""
-    def zero(arr):
-        z = jnp.zeros((arr.shape[0], size, *arr.shape[2:]), arr.dtype)
-        return jax.lax.dynamic_update_slice_in_dim(arr, z, base, axis=1)
-
-    if hasattr(cache, "kv"):          # hybrid / encdec: recurse
-        new = {"kv": _scrub_slots(cache.kv, base, size)}
-        if hasattr(cache, "state"):
-            new["state"] = _scrub_slots(cache.state, base, size)
-        if hasattr(cache, "cross_k"):  # encdec cross-attention pools
-            new["cross_k"] = zero(cache.cross_k)
-            new["cross_v"] = zero(cache.cross_v)
-        return dataclasses.replace(cache, **new)
-    if hasattr(cache, "pools"):
-        return dataclasses.replace(
-            cache, pools={k: zero(v) for k, v in cache.pools.items()})
-    return dataclasses.replace(cache, k=zero(cache.k), v=zero(cache.v))
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b")
@@ -1487,9 +1329,6 @@ def main(argv=None):
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--no-guard", action="store_true")
-    ap.add_argument("--no-jit", action="store_true",
-                    help="eager trusted steps (the bit-identical fallback "
-                         "to the compiled step path)")
     ap.add_argument("--engines", type=int, default=1,
                     help="co-hosted engines sharing one manager; >1 "
                          "exercises the multi-engine fused decode")
@@ -1521,21 +1360,19 @@ def main(argv=None):
     if args.reduced:
         cfg = cfg.reduced()
     if args.engines > 1:
-        mgr = make_shared_manager(args.engines, max_batch=8,
-                                  jit_trusted=not args.no_jit)
+        mgr = make_shared_manager(args.engines, max_batch=8)
         engines = [ServeEngine(cfg, max_batch=8, max_len=256,
                                guard=not args.no_guard, manager=mgr)
                    for _ in range(args.engines)]
     else:
         engines = [ServeEngine(cfg, max_batch=8, max_len=256,
-                               guard=not args.no_guard,
-                               jit_steps=not args.no_jit)]
+                               guard=not args.no_guard)]
     pols = [FencePolicy(p.strip()) for p in args.policies.split(",")
             if p.strip()]
     aliases = {"lc": "latency_critical", "be": "best_effort", "-": None}
     classes = [aliases.get(c.strip(), c.strip())
                for c in args.classes.split(",") if c.strip()]
-    per = max(engines[0]._pool_slots()
+    per = max(KV.pool_slots(engines[0].cache)
               // max(args.tenants * len(engines), 1) // 2, 2)
     for e, eng in enumerate(engines):
         for t in range(args.tenants):

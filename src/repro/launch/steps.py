@@ -272,50 +272,9 @@ _KV_DTYPES = {"bf16": jnp.bfloat16, "f32": jnp.float32,
               "f8": jnp.float8_e4m3fn}
 
 
-def split_cache_pool(cache) -> Tuple[Dict, Any]:
-    """Split a serve cache pytree into ``(pool, meta)``.
-
-    ``pool`` collects the slot-indexed tensors (KV page pools, SSM state
-    pools, cross-attention pools — everything partitioned on the shared
-    slot axis); ``meta`` is the same cache dataclass with the pool fields
-    stripped (per-batch-row state only: slot ids, seq lens, page tables).
-    The pool half lives as a manager-owned :class:`PoolArena` so N serve
-    engines address ONE slot space; the meta half stays a per-engine step
-    operand.  Inverse of :func:`join_cache_pool`.
-    """
-    if hasattr(cache, "kv"):          # hybrid / encdec: recurse
-        kv_pool, kv_meta = split_cache_pool(cache.kv)
-        pool: Dict[str, Any] = {"kv": kv_pool}
-        repl: Dict[str, Any] = {"kv": kv_meta}
-        if hasattr(cache, "state"):
-            sp, sm = split_cache_pool(cache.state)
-            pool["state"] = sp
-            repl["state"] = sm
-        if hasattr(cache, "cross_k"):
-            pool["cross_k"] = cache.cross_k
-            pool["cross_v"] = cache.cross_v
-            repl["cross_k"] = None
-            repl["cross_v"] = None
-        return pool, dataclasses.replace(cache, **repl)
-    if hasattr(cache, "pools"):
-        return {"pools": cache.pools}, dataclasses.replace(cache, pools={})
-    return {"k": cache.k, "v": cache.v}, \
-        dataclasses.replace(cache, k=None, v=None)
-
-
-def join_cache_pool(pool: Dict, meta) -> Any:
-    """Rebuild the full cache pytree from a pool dict + meta cache."""
-    if hasattr(meta, "kv"):
-        repl: Dict[str, Any] = {"kv": join_cache_pool(pool["kv"], meta.kv)}
-        if hasattr(meta, "state"):
-            repl["state"] = join_cache_pool(pool["state"], meta.state)
-        if hasattr(meta, "cross_k"):
-            repl["cross_k"] = pool["cross_k"]
-            repl["cross_v"] = pool["cross_v"]
-        return dataclasses.replace(meta, **repl)
-    if hasattr(meta, "pools"):
-        return dataclasses.replace(meta, pools=pool["pools"])
-    return dataclasses.replace(meta, k=pool["k"], v=pool["v"])
+#: a serve cache's ``(pool, meta)`` halves and back (``models/kvcache.py``)
+split_cache_pool = KV.split_pool
+join_cache_pool = KV.join_pool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -328,13 +287,13 @@ class TrustedStepBundle:
     through (``fn(arena, pool, params, meta, x, guard) ->
     (arena, pool, (meta, next_ids))``).
 
-    Names carry a pool fingerprint (model shape + pool geometry) so
-    engines serving the *same* model shape share one symbol entry — and
-    therefore one compiled step that the scheduler can fuse across
-    engines, all addressing one manager-owned pool — while engines
-    serving different models stay on separate entries (a shared name with
-    different step functions would silently run the first engine's model
-    for everyone).
+    Names carry the model's step id (``ModelAPI.step_id``) and the
+    pool's geometry, so engines serving the *same* model share one symbol
+    entry — and therefore one compiled step that the scheduler can fuse
+    across engines, all addressing one manager-owned pool — while engines
+    serving different models stay on separate entries (a shared name
+    with different step functions would silently run the first engine's
+    model for everyone).
     """
 
     pool_name: str
@@ -380,10 +339,10 @@ def build_trusted_serve_steps(api: ModelAPI, pool_key: str,
     ``pool_key`` must identify the pool geometry (slot count, page
     layout); ``step_key`` (default: ``pool_key``) additionally carries
     the model identity when the pool is the *global paged* layout shared
-    by engines serving different model shapes — such engines address one
-    pool arena but keep distinct step symbols (a shared name with
-    different step functions would silently run the first engine's model
-    for everyone).
+    by engines serving different models — such engines address one pool
+    arena but keep distinct step symbols (a shared name with different
+    step functions would silently run the first engine's model for
+    everyone).
 
     ``temperature > 0`` builds the *sampled* decode step: its token
     operand is ``(toks, key)`` — the PRNG key threads as an operand, so
@@ -536,18 +495,11 @@ def build_pool_relocation_step(src: int, dst: int, size: int) -> Callable:
     return relocate
 
 
-def _cache_shape_for(api: ModelAPI, cfg: ModelConfig, shape: ShapeConfig,
+def _cache_shape_for(api: ModelAPI, shape: ShapeConfig,
                      kv_dtype: str = "bf16"):
-    fam = cfg.family
-    if fam == "ssm":
-        return jax.eval_shape(
-            functools.partial(api.init_cache, shape.global_batch))
-    dt = _KV_DTYPES[kv_dtype]
-    if fam == "encdec":
-        return jax.eval_shape(functools.partial(
-            api.init_cache, shape.global_batch, shape.seq_len, dtype=dt))
     return jax.eval_shape(functools.partial(
-        api.init_cache, shape.global_batch, shape.seq_len, dtype=dt))
+        api.init_cache, shape.global_batch, shape.seq_len,
+        dtype=_KV_DTYPES[kv_dtype]))
 
 
 def build_prefill_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
@@ -565,7 +517,7 @@ def build_prefill_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
         return cache, logits
 
     params_shape = jax.eval_shape(api.init, jax.random.PRNGKey(0))
-    cache_shape = _cache_shape_for(api, cfg, shape, kv_dtype)
+    cache_shape = _cache_shape_for(api, shape, kv_dtype)
     batch_specs = api.batch_specs(shape)
 
     p_shard = _shard_tree(mesh, rules, api.param_logical_axes(),
@@ -605,7 +557,7 @@ def build_decode_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
         return cache, logits
 
     params_shape = jax.eval_shape(api.init, jax.random.PRNGKey(0))
-    cache_shape = _cache_shape_for(api, cfg, shape, kv_dtype)
+    cache_shape = _cache_shape_for(api, shape, kv_dtype)
     tokens_spec = jax.ShapeDtypeStruct((shape.global_batch,), jnp.int32)
 
     p_shard = _shard_tree(mesh, rules, api.param_logical_axes(),

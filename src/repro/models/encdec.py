@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -182,6 +182,9 @@ def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class EncDecCache:
+    POOL: ClassVar[Tuple[str, ...]] = ("cross_k", "cross_v")
+    META: ClassVar[Tuple[str, ...]] = ("src_lens",)
+
     kv: KV.PagedKVCache            # decoder self-attention
     cross_k: jax.Array             # (L, slots, S_src, KH, D)
     cross_v: jax.Array
@@ -189,8 +192,8 @@ class EncDecCache:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, src_len: int,
-               dtype=jnp.bfloat16) -> EncDecCache:
-    kv = KV.init_kv_cache(cfg, batch, max_len, dtype=dtype)
+               dtype=jnp.bfloat16, slots=None) -> EncDecCache:
+    kv = KV.init_kv_cache(cfg, batch, max_len, dtype=dtype, slots=slots)
     slots = kv.k.shape[1]
     shape = (cfg.n_layers, slots, src_len, cfg.n_kv_heads, cfg.head_dim)
     return EncDecCache(

@@ -17,7 +17,7 @@ Serving state per request: 13 windowed-KV slabs (one per attn invocation)
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -167,6 +167,10 @@ def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class HybridCache:
+    #: no fields of its own: both parts are caches (kvcache's walks)
+    POOL: ClassVar[Tuple[str, ...]] = ()
+    META: ClassVar[Tuple[str, ...]] = ()
+
     kv: KV.PagedKVCache          # n_attn_calls layers, windowed
     state: KV.StateCache         # per-mamba-layer ssm + conv state
 

@@ -28,12 +28,20 @@ Two levels of pooling (see DESIGN.md §Hardware adaptation):
 
 SSM/recurrent state uses the same slot discipline: ``(L, slots, ...state)``
 with fenced slot ids (space "state").
+
+Every serve cache type names its fields once, as class attributes:
+``POOL`` are the tensors indexed by slot (or, in the global layout, by
+page) on axis 1, which the manager owns as one pool arena; ``META`` are
+the per-batch-row fields (slot ids, seq lens, page tables) that travel as
+an engine's step operand; any other field is a cache of its own.  The
+walks at the end of this module (split and join, slot count, slot ids,
+seq-len reset, scrub, pages per slot) read only those declarations.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, ClassVar, Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +69,9 @@ class PagedKVCache:
       *virtual* page ids into the shared pool (slot_ids are unused and
       kept only for pytree-shape compatibility).
     """
+
+    POOL: ClassVar[Tuple[str, ...]] = ("k", "v")
+    META: ClassVar[Tuple[str, ...]] = ("page_table", "slot_ids", "seq_lens")
 
     k: jax.Array
     v: jax.Array
@@ -127,6 +138,16 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, *,
         slot_ids=jnp.arange(batch, dtype=jnp.int32),
         seq_lens=jnp.zeros((batch,), jnp.int32),
     )
+
+
+def global_pool_key(cfg: ModelConfig, total_pages: int, dtype) -> str:
+    """The global paged pool's name: its KV geometry and dtype, and no
+    model identity, so engines serving different models whose geometry
+    and dtype are equal share one pool (their steps keep names of their
+    own)."""
+    return (f"paged:L{cfg.decoder_layers}:kh{cfg.n_kv_heads}:"
+            f"d{cfg.head_dim}:pg{PAGE_SIZE}:P{total_pages}:"
+            f"{jnp.dtype(dtype).name}")
 
 
 def init_global_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
@@ -408,6 +429,9 @@ class StateCache:
     slot ids are fenced with the tenant's partition (space "state").
     """
 
+    POOL: ClassVar[Tuple[str, ...]] = ("pools",)
+    META: ClassVar[Tuple[str, ...]] = ("slot_ids", "seq_lens")
+
     pools: Dict[str, jax.Array]
     slot_ids: jax.Array        # (B,)
     seq_lens: jax.Array        # (B,)
@@ -428,3 +452,85 @@ class StateCache:
         pools[name] = pools[name].at[ll, slots].set(
             value, mode="promise_in_bounds")
         return dataclasses.replace(self, pools=pools)
+
+
+# ---------------------------------------------------------------------------
+# Walks over the declared fields (module docstring): pool and meta
+# ---------------------------------------------------------------------------
+
+def _parts(cache) -> Tuple[str, ...]:
+    """The fields of ``cache`` that are caches of their own."""
+    own = cache.POOL + cache.META
+    return tuple(f.name for f in dataclasses.fields(cache)
+                 if f.name not in own)
+
+
+def _walk(cache) -> Iterator[Any]:
+    yield cache
+    for name in _parts(cache):
+        yield from _walk(getattr(cache, name))
+
+
+def split_pool(cache) -> Tuple[Dict[str, Any], Any]:
+    """Split a serve cache into ``(pool, meta)``: ``pool`` is a dict of the
+    ``POOL`` fields (nested by part), ``meta`` the same cache with them set
+    to None.  The pool lives as a manager-owned arena, so N engines address
+    one slot space; the meta stays an engine's step operand."""
+    pool: Dict[str, Any] = {}
+    meta: Dict[str, Any] = {}
+    for name in _parts(cache):
+        pool[name], meta[name] = split_pool(getattr(cache, name))
+    for name in cache.POOL:
+        pool[name], meta[name] = getattr(cache, name), None
+    return pool, dataclasses.replace(cache, **meta)
+
+
+def join_pool(pool: Dict[str, Any], meta) -> Any:
+    """The cache that :func:`split_pool` split into ``pool`` and ``meta``."""
+    full = {name: join_pool(pool[name], getattr(meta, name))
+            for name in _parts(meta)}
+    full.update((name, pool[name]) for name in meta.POOL)
+    return dataclasses.replace(meta, **full)
+
+
+def _map_meta(cache, name: str, fn):
+    """``cache`` with ``fn`` applied to the meta field ``name`` of every
+    part that has one."""
+    new = {part: _map_meta(getattr(cache, part), name, fn)
+           for part in _parts(cache)}
+    if name in cache.META:
+        new[name] = fn(getattr(cache, name))
+    return dataclasses.replace(cache, **new)
+
+
+def with_slot_ids(meta, slot_ids: jax.Array):
+    """``meta`` with every part's rows on the slots ``slot_ids`` (B,)."""
+    return _map_meta(meta, "slot_ids", lambda _: slot_ids)
+
+
+def reset_seq_lens(meta):
+    """``meta`` with every part's sequences empty."""
+    return _map_meta(meta, "seq_lens", jnp.zeros_like)
+
+
+def pool_slots(cache) -> int:
+    """The pool's extent on axis 1: slots, or the global layout's pages."""
+    return jax.tree.leaves(split_pool(cache)[0])[0].shape[1]
+
+
+def pages_per_slot(cache) -> int:
+    """The page ids one slot may address: its slab's pages, every page of
+    the global pool, or 1 where no part holds KV pages."""
+    return max((c.pages_per_slot for c in _walk(cache)
+                if isinstance(c, PagedKVCache)), default=1)
+
+
+def scrub(cache, ids) -> Any:
+    """Zero the slots (or, in the global layout, the physical pages)
+    ``ids`` of every pool tensor: reclaimed slots hand over empty."""
+    if not ids:
+        return cache
+    idx = jnp.asarray(tuple(ids), jnp.int32)
+    pool, meta = split_pool(cache)
+    return join_pool(jax.tree.map(
+        lambda a: a.at[:, idx].set(jnp.zeros((), a.dtype)), pool), meta)

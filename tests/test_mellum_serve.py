@@ -27,13 +27,12 @@ from bench.weights import seed_key
 from repro.configs import FULL, SLIDING, get_config
 from repro.core.fence import FenceParams, FencePolicy
 from repro.launch.serve import (
-    KEYS_FULL,
-    KEYS_WINDOW,
     ServeEngine,
     make_shared_manager,
     serve_continuous,
 )
 from repro.models import get_model
+from repro.models.api import KEYS_FULL, KEYS_WINDOW
 from repro.models import kvcache as KV
 from repro.models import moe as MOE
 from repro.models.guard import GuardSpec

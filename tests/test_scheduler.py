@@ -1024,55 +1024,6 @@ def test_trusted_requests_fuse_when_jitted():
     assert any(k[0] == "trusted" for k in mgr.scheduler._fused_cache)
 
 
-def test_trusted_requests_stay_single_when_eager():
-    """jit_trusted=False is the eager fallback: trusted steps never fuse
-    and execute unjitted through the per-launch path — same results."""
-    mgr = GuardianManager(total_slots=64, jit_trusted=False)
-
-    def step(arena, x):
-        return arena, x * 2.0
-
-    mgr.register_trusted_kernel("step", step)
-    a = mgr.register_tenant("a", 8)
-    b = mgr.register_tenant("b", 8)
-    ra = a.launch_kernel("step", args=(jnp.ones((4,), jnp.float32),))
-    rb = b.launch_kernel("step", args=(jnp.full((4,), 3.0, jnp.float32),))
-    mgr.synchronize()
-    st_ = mgr.scheduler.stats
-    assert st_.fused_steps == 0 and st_.single_steps == 2
-    np.testing.assert_array_equal(np.asarray(ra.result),
-                                  np.full(4, 2.0, np.float32))
-    np.testing.assert_array_equal(np.asarray(rb.result),
-                                  np.full(4, 6.0, np.float32))
-    entry = mgr.pointer_to_symbol["step"]
-    assert not any(k[0] == "trusted" for k in entry.jit_cache)
-
-
-def test_trusted_jit_matches_eager_results():
-    """The compiled trusted step is bit-identical to the eager fallback —
-    same arena bytes, same outputs (regression for the --no-jit path)."""
-    outs, arenas = [], []
-    for jit in (True, False):
-        mgr = GuardianManager(total_slots=64, jit_trusted=jit)
-
-        def step(arena, x, w):
-            h = jnp.tanh(x @ w) + x
-            return arena, h
-
-        mgr.register_trusted_kernel("step", step)
-        c = mgr.register_tenant("svc", 16)
-        x = jnp.asarray(np.linspace(-1, 1, 32, dtype=np.float32)
-                        .reshape(4, 8))
-        w = jnp.asarray(np.linspace(1, -1, 64, dtype=np.float32)
-                        .reshape(8, 8))
-        req = c.launch_kernel("step", args=(x, w))
-        mgr.synchronize()
-        outs.append(np.asarray(req.result))
-        arenas.append(np.asarray(mgr.arena.buf))
-    np.testing.assert_array_equal(outs[0], outs[1])
-    np.testing.assert_array_equal(arenas[0], arenas[1])
-
-
 def test_trusted_pytree_operands_fuse_by_structure():
     """Trusted signatures hash pytree operands (params/cache/guard trees)
     by treedef + leaf structure: equal-structure steps fuse, different

@@ -16,12 +16,9 @@ from repro.configs import get_config, list_archs
 from repro.launch.serve import ServeEngine
 
 #: families the text serve path cannot drive yet, with the exact reason
-#: (kept in sync with benchmarks/production_trace.py's fleet choice)
 UNSERVABLE = {
     "vlm": ("prefill requires the vision 'patches' input the text-only "
             "serve path does not synthesize"),
-    "encdec": ("encoder-decoder cache API lacks the slab engine's "
-               "per-slot init (init_cache() has no slots parameter)"),
 }
 
 
@@ -51,4 +48,5 @@ def test_unservable_reasons_are_current():
     parametrized case above or listed with a reason."""
     families = {get_config(a).family for a in list_archs()}
     assert set(UNSERVABLE) <= families
-    assert families - set(UNSERVABLE) >= {"dense", "moe", "ssm", "hybrid"}
+    assert families - set(UNSERVABLE) >= {"dense", "moe", "ssm", "hybrid",
+                                          "encdec"}

@@ -8,8 +8,8 @@ monotonic timestamps), the manager-plane instrumentation (drain cycles,
 queue age, quarantine gauges, lifecycle events), and the three headline
 invariants:
 
-* logical metrics are **bit-identical** between ``jit_steps=True`` and
-  ``jit_steps=False`` serve runs (wall-clock series are excluded via
+* logical metrics are **bit-identical** between two serve runs of the
+  same requests (wall-clock series are excluded via
   ``snapshot(include_timing=False)``);
 * ``telemetry=False`` is byte-identical on the data plane and leaves
   the registry/trace empty;
@@ -189,7 +189,7 @@ def _feed(reg, ops):
 
 def test_registry_determinism_sweep():
     """Two registries fed the same op sequence are bit-identical — the
-    substrate of the jit-vs-eager metrics comparison."""
+    substrate of the run-to-run serve metrics comparison."""
     rng = np.random.default_rng(1)
     ops = [(int(rng.integers(0, 3)), f"m{rng.integers(3)}",
             int(rng.integers(1, 9)),
@@ -394,16 +394,16 @@ def test_quarantine_gauges_counters_and_events():
 
 
 # ---------------------------------------------------------------------------
-# Serving plane: jit/eager bit-identity + request counters
+# Serving plane: run-to-run bit-identity + request counters
 # ---------------------------------------------------------------------------
 
 
-def _serve_run(jit_steps):
+def _serve_run():
     from repro.configs import get_config
     from repro.launch.serve import ServeEngine
 
     cfg = get_config("stablelm-3b").reduced()
-    eng = ServeEngine(cfg, max_batch=4, max_len=16, jit_steps=jit_steps)
+    eng = ServeEngine(cfg, max_batch=4, max_len=16)
     eng.register_tenant("t0", 2)
     eng.register_tenant("t1", 2)
     rng = np.random.default_rng(0)
@@ -414,17 +414,18 @@ def _serve_run(jit_steps):
 
 
 def test_serve_metrics_bit_identical_jit_vs_eager():
-    """The compiled and eager trusted-step paths must agree on every
-    logical metric (wall-clock histograms excluded) AND on the tokens —
-    telemetry must not observe the implementation, only the schedule."""
-    outs_j, tel_j = _serve_run(True)
-    outs_e, tel_e = _serve_run(False)
-    assert outs_j == outs_e
-    snap_j = tel_j.registry.snapshot(include_timing=False)
-    snap_e = tel_e.registry.snapshot(include_timing=False)
-    assert snap_j == snap_e
-    assert tel_j.registry.counter("requests", tenant="t0") == 1
-    assert tel_j.registry.counter("requests", tenant="t1") == 1
+    """Two runs of the compiled trusted steps, on fresh engines, agree on
+    every logical metric (wall-clock histograms excluded) AND on the
+    tokens — telemetry observes the schedule, not the implementation —
+    and count each tenant's request once."""
+    outs_a, tel_a = _serve_run()
+    outs_b, tel_b = _serve_run()
+    assert outs_a == outs_b
+    snap_a = tel_a.registry.snapshot(include_timing=False)
+    snap_b = tel_b.registry.snapshot(include_timing=False)
+    assert snap_a == snap_b
+    assert tel_a.registry.counter("requests", tenant="t0") == 1
+    assert tel_a.registry.counter("requests", tenant="t1") == 1
 
 
 def test_shared_manager_refuses_per_engine_telemetry_override():
